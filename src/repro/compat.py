@@ -1,76 +1,52 @@
-"""Version compatibility shims for the jax APIs this repo uses.
+"""The jax APIs this repo uses, spelled once for the installed jax (0.9).
 
-The codebase targets the current jax API names; older installed versions
-(0.4.x) spell several of them differently.  Every use site imports the
-canonical name from here instead of sniffing versions locally:
+Every use site imports these names from here:
 
-  * ``VMEM`` / ``CompilerParams`` — Pallas TPU scratch + params
-    (``pltpu.MemorySpace.VMEM`` / ``pltpu.CompilerParams`` on new jax,
-    ``pltpu.VMEM`` / ``pltpu.TPUCompilerParams`` on 0.4.x).
-  * ``set_mesh(mesh)`` — context manager installing `mesh` as the ambient
-    mesh (``jax.sharding.set_mesh`` / ``use_mesh`` on new jax; on 0.4.x the
-    ``Mesh`` object itself is the context manager).
-  * ``get_abstract_mesh()`` — the ambient mesh for sharding constraints, or
-    None when outside any mesh context.
+  * ``VMEM`` / ``CompilerParams`` — Pallas TPU scratch + params.
+  * ``make_mesh(shape, axes)`` — a mesh whose axes are ``AxisType.Auto``.
+    jax 0.9's ``jax.make_mesh`` defaults to Explicit axes, which change how
+    jit places un-annotated arrays; the repo's sharding rules are written
+    for Auto (``with_sharding_constraint`` + ``in_shardings``).
+  * ``shard_map`` — ``jax.shard_map`` called with its mesh installed as the
+    ambient mesh, so an eager call works without the caller entering
+    ``set_mesh`` first.
+  * ``set_mesh(mesh)`` / ``get_abstract_mesh()`` — the ambient mesh.
 """
 from __future__ import annotations
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType
 
-# --- Pallas TPU names ------------------------------------------------------
-
-_mem = getattr(pltpu, "MemorySpace", None)
-VMEM = getattr(_mem, "VMEM", None) if _mem is not None else None
-if VMEM is None or not callable(VMEM):
-    VMEM = pltpu.VMEM
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+VMEM = pltpu.MemorySpace.VMEM
+CompilerParams = pltpu.CompilerParams
+pcast = jax.lax.pcast
+set_mesh = jax.sharding.set_mesh
 
 
-# --- shard_map -------------------------------------------------------------
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """jax.shard_map (new) / jax.experimental.shard_map.shard_map (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def pcast(x, axis_name, *, to):
-    """jax.lax.pcast (VMA re-tagging inside shard_map, jax >= 0.8).  Older
-    jax has no varying-manual-axes tracking, so the cast is a no-op there."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is not None:
-        return fn(x, axis_name, to=to)
-    return x
-
-
-# --- Mesh context ----------------------------------------------------------
-
-def set_mesh(mesh):
-    """Context manager making `mesh` the ambient mesh for jit/constraints."""
-    setter = getattr(jax.sharding, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # 0.4.x: `with mesh:` installs the thread-local mesh
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def get_abstract_mesh():
-    """The ambient mesh (abstract or physical), or None outside a mesh
-    context.  Callers treat None and `mesh.empty` as 'no mesh'."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as _mesh  # 0.4.x fallback
-    am = getattr(_mesh, "get_abstract_mesh", lambda: None)()
-    if isinstance(am, (_mesh.Mesh, _mesh.AbstractMesh)) and not am.empty:
-        return am
-    phys = _mesh.thread_resources.env.physical_mesh
-    if phys is not None and not phys.empty:
-        return phys
-    return None
+    """The ambient mesh, or None outside a mesh context."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def shard_map(f, *, mesh, in_specs, out_specs, **kw):
+    """``jax.shard_map`` over `mesh`; outside any mesh context the call
+    runs under ``set_mesh(mesh)``."""
+    mapped = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, **kw)
+
+    def call(*args):
+        if get_abstract_mesh() is not None:
+            return mapped(*args)
+        with set_mesh(mesh):
+            return mapped(*args)
+
+    return call

@@ -12,9 +12,14 @@ which pays both per decode step per layer.
 
 Layout (flash decoding, split-KV):
 
-* grid ``(B, KVH, kv_splits, pages_per_split)`` — the innermost dimension
+* grid ``(B, kv_splits, pages_per_split)`` — the innermost dimension
   walks one split's slice of the request's block table sequentially
-  ("arbitrary"); batch / kv-head / split are parallel.
+  ("arbitrary"); batch / split are parallel.
+* Each step fetches one WHOLE page, ``(1, BS, KVH, D)``, and loops over
+  the KV heads inside the kernel.  Mosaic requires a block's last two dims
+  to be (8, 128)-divisible or whole, so a per-head ``(1, BS, 1, D)`` block
+  of the ``[NB, BS, KVH, D]`` pool cannot compile for a TPU; the whole
+  page can, and each page is DMA'd once instead of once per head.
 * The block tables and per-request lengths ride in as **scalar prefetch**
   (``PrefetchScalarGridSpec``): the page index map reads
   ``block_tables[b, split*P + p]`` before the body runs, so the pipeline
@@ -25,12 +30,12 @@ Layout (flash decoding, split-KV):
   index skip the re-fetch, so HBM traffic per request scales with its live
   tokens, not with the pool size or the table width; the clamped steps'
   compute is skipped with ``pl.when``.
-* Each program keeps ``(m, l, acc)`` carry in VMEM scratch and emits its
-  split's partial ``(acc, m, l)``; the cross-split combine is a tiny
-  logsumexp merge done by the wrapper (:func:`..ops.merge_splits`).
+* Each program keeps a per-head ``(m, l, acc)`` carry in VMEM scratch and
+  emits its split's partial ``(acc, m, l)``; the cross-split combine is a
+  tiny logsumexp merge done by the wrapper (:func:`..ops.merge_splits`).
 
-The int8 variant streams ``[BS, D]`` int8 codes plus the ``[BS]``
-per-token-head scale lane and dequantizes in-registers (KIVI-style grid,
+The int8 variant streams the page's int8 codes plus its ``[BS, KVH]``
+per-token-head scale tile and dequantizes in-registers (KIVI-style grid,
 identical to ``attention.dequantize_kv``).  Unlike the gather reference's
 fully-integer path it keeps q and the probabilities in f32 — the int8 win
 here is HBM bytes, not MXU width — so parity with the int8 reference is
@@ -38,7 +43,8 @@ close-not-bitwise (the reference additionally quantizes q and p; see
 tests/test_paged_attention.py).
 
 **Flash prefill** (:func:`flash_prefill_kernel`) extends the same layout
-to causal prompt chunks: grid ``(B, KVH, past_pages + 1 + chunk_pages)``
+to causal prompt chunks: grid ``(B, past_pages + 1 + chunk_pages)``, whole
+pages and an in-kernel head loop as above,
 first walks the request's past pages (identical scalar-prefetch
 indirection and dead-step clamping), then runs the causal self tile on
 the in-hand chunk (kept fp, like the one-shot prefill's ``attend_full``),
@@ -51,11 +57,12 @@ block keeps its bytes (tested).  The in-kernel int8 quantization
 reproduces ``attention.quantize_kv`` bit-exactly (f32 absmax / 127,
 bf16-rounded scale), so chunked pools match ``pack_prompt``-packed pools.
 
-TPU notes: block shapes follow the model's (G, D) head geometry; on real
-hardware D is the 128-lane dim (head_dim 64/128) while G stays small —
-fine for VPU-bound decode.  CPU CI runs the kernel in interpret mode for
-parity only (per-grid-step interpreter overhead makes it slow); the fast
-CPU path is :func:`..ops.flash_decode_jnp` /
+TPU notes: D is the 128-lane dim (head_dim 64/128) and KVH the sublane
+dim of every page block; G stays small — fine for VPU-bound decode.
+tests/test_tpu_compile.py compiles both kernels for a described v5e at
+block sizes 16 and 32, fp and int8.  CPU CI runs the kernel in interpret
+mode for parity only (per-grid-step interpreter overhead makes it slow);
+the fast CPU path is :func:`..ops.flash_decode_jnp` /
 :func:`..ops.flash_prefill_jnp`, the same math vectorized.
 """
 from __future__ import annotations
@@ -73,16 +80,24 @@ from repro import compat
 NEG_INF = -1e30
 
 
+def _col(tile, h: int):
+    """Column ``h`` of a ``[R, KVH]`` tile as ``[R, 1]`` (exact: a masked
+    lane sum, which Mosaic lowers without a lane-offset slice)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.sum(jnp.where(lane == h, tile, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(
     bt_ref,       # [B, W] int32  (scalar prefetch)
     nv_ref,       # [B]    int32  (scalar prefetch)
-    q_ref,        # [1, 1, G, D]
-    k_ref,        # [1, BS, 1, D] (int8 or fp page slice for this kv head)
+    q_ref,        # [1, KVH, G, D]
+    k_ref,        # [1, BS, KVH, D] (int8 or fp page, all kv heads)
     *rest,        # (k_scale, v, v_scale | v), out, m, l, scratches
     bs: int,
     pages_per_split: int,
     width: int,
     d: int,
+    kvh: int,
     int8: bool,
 ):
     if int8:
@@ -94,8 +109,8 @@ def _kernel(
     out_ref, m_ref, l_ref, acc_scr, m_scr, l_scr = rest
 
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    p = pl.program_id(3)
+    s = pl.program_id(1)
+    p = pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -109,37 +124,42 @@ def _kernel(
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # [BS, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if int8:
-            # In-register dequant: the page never exists in fp outside VMEM.
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        srs = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / np.sqrt(d)   # [G, BS]
         pos = page * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         valid = pos < nv                                        # [1, BS]
-        srs = jnp.where(valid, srs, NEG_INF)
-        m_prev = m_scr[...]                                     # [G, 1]
-        m_new = jnp.maximum(m_prev, srs.max(-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # Explicit zeroing of masked probabilities: for a live page m_new is
-        # a real score, so exp(NEG_INF - m_new) underflows to 0 anyway —
-        # this just keeps fully-masked tails exact.
-        prob = jnp.where(valid, jnp.exp(srs - m_new), 0.0)
-        l_scr[...] = l_scr[...] * alpha + prob.sum(-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            prob, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        if int8:
+            ks = ks_ref[0].astype(jnp.float32)                  # [BS, KVH]
+            vs = vs_ref[0].astype(jnp.float32)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)                 # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)           # [BS, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if int8:
+                # In-register dequant: the page never exists in fp
+                # outside VMEM.
+                k = k * _col(ks, h)
+                v = v * _col(vs, h)
+            srs = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / np.sqrt(d)  # [G, BS]
+            srs = jnp.where(valid, srs, NEG_INF)
+            m_prev = m_scr[h]                                   # [G, 1]
+            m_new = jnp.maximum(m_prev, srs.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # Explicit zeroing of masked probabilities: for a live page
+            # m_new is a real score, so exp(NEG_INF - m_new) underflows to
+            # 0 anyway — this just keeps fully-masked tails exact.
+            prob = jnp.where(valid, jnp.exp(srs - m_new), 0.0)
+            l_scr[h] = l_scr[h] * alpha + prob.sum(-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                prob, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(p == pages_per_split - 1)
     def _flush():
-        out_ref[0, 0, 0] = acc_scr[...]
-        m_ref[0, 0, 0] = m_scr[...]
-        l_ref[0, 0, 0] = l_scr[...]
+        out_ref[0, :, 0] = acc_scr[...]
+        m_ref[0, :, 0] = m_scr[...]
+        l_ref[0, :, 0] = l_scr[...]
 
 
 @functools.partial(
@@ -167,53 +187,53 @@ def paged_attention_kernel(
     ns = max(1, min(kv_splits, width))
     pps = -(-width // ns)
 
-    def page_map(bi, hi, si, pi, bt, nv):
+    def page_map(bi, si, pi, bt, nv):
         gidx = si * pps + pi
         # Clamp to the request's last live page: repeated block indices on
         # consecutive steps elide the DMA, so dead table tail entries cost
         # no HBM traffic (their compute is pl.when-skipped too).
         live_last = jnp.maximum(jax.lax.div(nv[bi] - 1, bs), 0)
         gidx = jnp.minimum(jnp.minimum(gidx, live_last), width - 1)
-        return (bt[bi, gidx], 0, hi, 0)
+        return (bt[bi, gidx], 0, 0, 0)
 
-    def scale_map(bi, hi, si, pi, bt, nv):
-        return page_map(bi, hi, si, pi, bt, nv)[:3]
+    def scale_map(bi, si, pi, bt, nv):
+        return page_map(bi, si, pi, bt, nv)[:3]
 
-    def out_map(bi, hi, si, pi, bt, nv):
-        return (bi, hi, si, 0, 0)
+    def out_map(bi, si, pi, bt, nv):
+        return (bi, 0, si, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, pi, bt, nv:
-                     (bi, hi, 0, 0)),
-        pl.BlockSpec((1, bs, 1, d), page_map),
+        pl.BlockSpec((1, kvh, g, d), lambda bi, si, pi, bt, nv:
+                     (bi, 0, 0, 0)),
+        pl.BlockSpec((1, bs, kvh, d), page_map),
     ]
     args = [block_tables, n_valid, q, k_pages]
     if int8:
-        in_specs.append(pl.BlockSpec((1, bs, 1), scale_map))
+        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
         args.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, bs, 1, d), page_map))
+    in_specs.append(pl.BlockSpec((1, bs, kvh, d), page_map))
     args.append(v_pages)
     if int8:
-        in_specs.append(pl.BlockSpec((1, bs, 1), scale_map))
+        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
         args.append(v_scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, ns, pps),
+        grid=(b, ns, pps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, 1, g, d), out_map),
-            pl.BlockSpec((1, 1, 1, g, 1), out_map),
-            pl.BlockSpec((1, 1, 1, g, 1), out_map),
+            pl.BlockSpec((1, kvh, 1, g, d), out_map),
+            pl.BlockSpec((1, kvh, 1, g, 1), out_map),
+            pl.BlockSpec((1, kvh, 1, g, 1), out_map),
         ],
         scratch_shapes=[
-            compat.VMEM((g, d), jnp.float32),
-            compat.VMEM((g, 1), jnp.float32),
-            compat.VMEM((g, 1), jnp.float32),
+            compat.VMEM((kvh, g, d), jnp.float32),
+            compat.VMEM((kvh, g, 1), jnp.float32),
+            compat.VMEM((kvh, g, 1), jnp.float32),
         ],
     )
     kern = functools.partial(_kernel, bs=bs, pages_per_split=pps,
-                             width=width, d=d, int8=int8)
+                             width=width, d=d, kvh=kvh, int8=int8)
     acc, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -223,8 +243,7 @@ def paged_attention_kernel(
             jax.ShapeDtypeStruct((b, kvh, ns, g, 1), jnp.float32),
         ],
         compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_attention_decode",
@@ -241,16 +260,17 @@ def _prefill_kernel(
     pos_ref,      # [B]    int32   chunk start = tokens already in the pool
     nt_ref,       # [B]    int32   valid tokens in this chunk (ragged tail)
     wm_ref,       # [B]    int32   1 = row is prefilling this chunk
-    q_ref,        # [1, 1, C*G, D]
-    kn_ref,       # [1, C, 1, D]   in-hand chunk K (fp, post-RoPE)
-    vn_ref,       # [1, C, 1, D]
-    k_ref,        # [1, BS, 1, D]  pool page slice for this kv head
+    q_ref,        # [1, KVH, C*G, D]
+    kn_ref,       # [1, C, KVH, D] in-hand chunk K (fp, post-RoPE)
+    vn_ref,       # [1, C, KVH, D]
+    k_ref,        # [1, BS, KVH, D] pool page, all kv heads
     *rest,        # (k_scale, v, v_scale | v), outs, scratches
     bs: int,
     width: int,
     c: int,
     g: int,
     d: int,
+    kvh: int,
     int8: bool,
     out_dtype,
 ):
@@ -268,7 +288,7 @@ def _prefill_kernel(
         kso_ref = vso_ref = None
 
     b = pl.program_id(0)
-    t = pl.program_id(2)
+    t = pl.program_id(1)
     pos = pos_ref[b]
     n_tok = nt_ref[b]
     on = wm_ref[b] != 0
@@ -282,73 +302,79 @@ def _prefill_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def online_update(srs, valid, v):
-        """One online-softmax accumulation step over [CG, N] scores."""
+    def online_update(h, srs, valid, v):
+        """One online-softmax accumulation step of kv head ``h`` over
+        [CG, N] scores."""
         srs = jnp.where(valid, srs, NEG_INF)
-        m_prev = m_scr[...]                                 # [CG, 1]
+        m_prev = m_scr[h]                                   # [CG, 1]
         m_new = jnp.maximum(m_prev, srs.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         prob = jnp.where(valid, jnp.exp(srs - m_new), 0.0)
-        l_scr[...] = l_scr[...] * alpha + prob.sum(-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        l_scr[h] = l_scr[h] * alpha + prob.sum(-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
             prob, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[h] = m_new
 
     # ---- past-page walk: every chunk query sees every past key ----------
     @pl.when((t < width) & on & (t * bs < pos))
     def _past():
-        q = q_ref[0, 0].astype(jnp.float32)                 # [CG, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [BS, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if int8:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        srs = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / np.sqrt(d)    # [CG, BS]
         kp = t * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        online_update(srs, kp < pos, v)
+        if int8:
+            ks = ks_ref[0].astype(jnp.float32)              # [BS, KVH]
+            vs = vs_ref[0].astype(jnp.float32)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)             # [CG, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)       # [BS, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if int8:
+                k = k * _col(ks, h)
+                v = v * _col(vs, h)
+            srs = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / np.sqrt(d)  # [CG, BS]
+            online_update(h, srs, kp < pos, v)
 
     # ---- self tile: causal within the chunk, in-hand fp K/V -------------
     @pl.when((t == width) & on)
     def _self():
-        q = q_ref[0, 0].astype(jnp.float32)                 # [CG, D]
-        k = kn_ref[0, :, 0, :].astype(jnp.float32)          # [C, D]
-        v = vn_ref[0, :, 0, :].astype(jnp.float32)
-        srs = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / np.sqrt(d)    # [CG, C]
         kj = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
-        online_update(srs, (kj <= qi) & (kj < n_tok), v)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)             # [CG, D]
+            k = kn_ref[0, :, h, :].astype(jnp.float32)      # [C, D]
+            v = vn_ref[0, :, h, :].astype(jnp.float32)
+            srs = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / np.sqrt(d)  # [CG, C]
+            online_update(h, srs, (kj <= qi) & (kj < n_tok), v)
 
     @pl.when(t == width)
     def _flush():
-        out_ref[0, 0] = (acc_scr[...]
-                         / jnp.maximum(l_scr[...], 1e-30)).astype(out_dtype)
+        out_ref[0] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(out_dtype)
 
     # ---- write phase: quantize the chunk K/V into its pool pages --------
     j = t - (width + 1)
     @pl.when((t > width) & on & (j * bs < n_tok))
     def _write():
-        ks = kn_ref[0, pl.ds(j * bs, bs), 0, :]             # [BS, D]
-        vs = vn_ref[0, pl.ds(j * bs, bs), 0, :]
-        if int8:
+        for src_ref, co, so in ((kn_ref, ko_ref, kso_ref),
+                                (vn_ref, vo_ref, vso_ref)):
+            if not int8:
+                co[0] = src_ref[0, pl.ds(j * bs, bs)].astype(co.dtype)
+                continue
             # Identical math to attention.quantize_kv: f32 absmax scale,
             # bf16 storage rounding, codes from the bf16-rounded scale.
-            for src, co, so in ((ks, ko_ref, kso_ref), (vs, vo_ref, vso_ref)):
-                x = src.astype(jnp.float32)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (bs, kvh), 1)
+            scales = jnp.zeros((bs, kvh), jnp.float32)
+            for h in range(kvh):
+                x = src_ref[0, pl.ds(j * bs, bs), h, :].astype(jnp.float32)
                 scale = jnp.maximum(
                     jnp.max(jnp.abs(x), -1, keepdims=True) / 127.0,
-                    1e-8).astype(jnp.bfloat16)
-                codes = jnp.clip(
-                    jnp.round(x / scale.astype(jnp.float32)),
-                    -127, 127).astype(jnp.int8)
-                co[0, :, 0, :] = codes
-                so[0, :, 0] = scale[:, 0]
-        else:
-            ko_ref[0, :, 0, :] = ks.astype(ko_ref.dtype)
-            vo_ref[0, :, 0, :] = vs.astype(vo_ref.dtype)
+                    1e-8).astype(jnp.bfloat16).astype(jnp.float32)
+                co[0, :, h, :] = jnp.clip(
+                    jnp.round(x / scale), -127, 127).astype(jnp.int8)
+                scales = jnp.where(lane == h, scale, scales)
+            so[0] = scales.astype(so.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -372,11 +398,12 @@ def flash_prefill_kernel(
     same kernel — the prompt K/V never exists as a dense cache and never
     round-trips through a host-side ``pack_prompt`` scatter.
 
-    Grid ``(B, KVH, W + 1 + C/BS)``: the innermost dimension first walks
-    the request's past pages sequentially (scalar-prefetched block-table
-    indirection, dead steps clamped to the last live page so repeated
-    indices elide the DMA), then runs the causal self tile on the in-hand
-    chunk, then writes the chunk's pages.  The page *writes* go through
+    Grid ``(B, W + 1 + C/BS)`` over whole pages, heads looped in-kernel:
+    the innermost dimension first walks the request's past pages
+    sequentially (scalar-prefetched block-table indirection, dead steps
+    clamped to the last live page so repeated indices elide the DMA), then
+    runs the causal self tile on the in-hand chunk, then writes the
+    chunk's pages.  The page *writes* go through
     output index maps over the pool buffer itself (``input_output_aliases``),
     so masked rows (``write_mask`` 0) and dead tail steps land on the
     reserved null block 0 while every untouched pool block keeps its bytes.
@@ -396,27 +423,27 @@ def flash_prefill_kernel(
     assert (k_scale is not None) == int8, "int8 pages need scales"
     out_dtype = q.dtype
 
-    def q_map(bi, hi, ti, bt, ps, nt, wm):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ti, bt, ps, nt, wm):
+        return (bi, 0, 0, 0)
 
-    def new_map(bi, hi, ti, bt, ps, nt, wm):
-        return (bi, 0, hi, 0)
+    def new_map(bi, ti, bt, ps, nt, wm):
+        return (bi, 0, 0, 0)
 
-    def page_map(bi, hi, ti, bt, ps, nt, wm):
+    def page_map(bi, ti, bt, ps, nt, wm):
         # Past walk; dead steps (ti beyond the live past pages, or the
         # self/write phase) clamp to the last live past page so consecutive
         # repeats elide the DMA.
         live_last = jnp.maximum(jax.lax.div(ps[bi] - 1, bs), 0)
         i = jnp.minimum(jnp.minimum(ti, live_last), width - 1)
-        return (bt[bi, i], 0, hi, 0)
+        return (bt[bi, i], 0, 0, 0)
 
-    def scale_map(bi, hi, ti, bt, ps, nt, wm):
-        return page_map(bi, hi, ti, bt, ps, nt, wm)[:3]
+    def scale_map(bi, ti, bt, ps, nt, wm):
+        return page_map(bi, ti, bt, ps, nt, wm)[:3]
 
-    def out_map(bi, hi, ti, bt, ps, nt, wm):
-        return (bi, hi, 0, 0)
+    def out_map(bi, ti, bt, ps, nt, wm):
+        return (bi, 0, 0, 0)
 
-    def wr_map(bi, hi, ti, bt, ps, nt, wm):
+    def wr_map(bi, ti, bt, ps, nt, wm):
         # Write phase: chunk page j -> table slot pos/BS + j; anything else
         # (attention steps, masked rows, ragged dead tail) -> null block 0,
         # whose content is garbage by contract.
@@ -424,59 +451,67 @@ def flash_prefill_kernel(
         slot = jax.lax.div(ps[bi], bs) + jnp.maximum(j, 0)
         live = (j >= 0) & (wm[bi] != 0) & (j * bs < nt[bi]) & (slot < width)
         idx = jnp.where(live, bt[bi, jnp.minimum(slot, width - 1)], 0)
-        return (idx, 0, hi, 0)
+        return (idx, 0, 0, 0)
 
-    def wr_scale_map(bi, hi, ti, bt, ps, nt, wm):
-        return wr_map(bi, hi, ti, bt, ps, nt, wm)[:3]
+    def wr_scale_map(bi, ti, bt, ps, nt, wm):
+        return wr_map(bi, ti, bt, ps, nt, wm)[:3]
 
     in_specs = [
-        pl.BlockSpec((1, 1, cg, d), q_map),
-        pl.BlockSpec((1, c, 1, d), new_map),
-        pl.BlockSpec((1, c, 1, d), new_map),
-        pl.BlockSpec((1, bs, 1, d), page_map),
+        pl.BlockSpec((1, kvh, cg, d), q_map),
+        pl.BlockSpec((1, c, kvh, d), new_map),
+        pl.BlockSpec((1, c, kvh, d), new_map),
+        pl.BlockSpec((1, bs, kvh, d), page_map),
     ]
     args = [block_tables, pos, n_tok, write_mask, q, k_new, v_new, k_pages]
     if int8:
-        in_specs.append(pl.BlockSpec((1, bs, 1), scale_map))
+        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
         args.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, bs, 1, d), page_map))
+    in_specs.append(pl.BlockSpec((1, bs, kvh, d), page_map))
     args.append(v_pages)
     if int8:
-        in_specs.append(pl.BlockSpec((1, bs, 1), scale_map))
+        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
         args.append(v_scale)
 
-    out_specs = [pl.BlockSpec((1, 1, cg, d), out_map),
-                 pl.BlockSpec((1, bs, 1, d), wr_map)]
+    out_specs = [pl.BlockSpec((1, kvh, cg, d), out_map),
+                 pl.BlockSpec((1, bs, kvh, d), wr_map)]
     out_shape = [jax.ShapeDtypeStruct((b, kvh, cg, d), out_dtype),
                  jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)]
     # pallas_call input indices COUNT the scalar-prefetch args (tested:
     # the aliased pool buffers keep every unwritten block's bytes).
     if int8:
-        out_specs += [pl.BlockSpec((1, bs, 1), wr_scale_map),
-                      pl.BlockSpec((1, bs, 1, d), wr_map),
-                      pl.BlockSpec((1, bs, 1), wr_scale_map)]
+        out_specs += [pl.BlockSpec((1, bs, kvh), wr_scale_map),
+                      pl.BlockSpec((1, bs, kvh, d), wr_map),
+                      pl.BlockSpec((1, bs, kvh), wr_scale_map)]
         out_shape += [jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
                       jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
                       jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
         aliases = {7: 1, 8: 2, 9: 3, 10: 4}
     else:
-        out_specs.append(pl.BlockSpec((1, bs, 1, d), wr_map))
+        out_specs.append(pl.BlockSpec((1, bs, kvh, d), wr_map))
         out_shape.append(jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype))
         aliases = {7: 1, 8: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, kvh, width + 1 + cp),
+        grid=(b, width + 1 + cp),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            compat.VMEM((cg, d), jnp.float32),
-            compat.VMEM((cg, 1), jnp.float32),
-            compat.VMEM((cg, 1), jnp.float32),
+            compat.VMEM((kvh, cg, d), jnp.float32),
+            compat.VMEM((kvh, cg, 1), jnp.float32),
+            compat.VMEM((kvh, cg, 1), jnp.float32),
         ],
     )
     kern = functools.partial(_prefill_kernel, bs=bs, width=width, c=c, g=g,
-                             d=d, int8=int8, out_dtype=out_dtype)
+                             d=d, kvh=kvh, int8=int8, out_dtype=out_dtype)
+    # Scoped VMEM: double-buffered q/out and chunk K/V blocks, the f32
+    # (acc, m, l) carry (m/l pad to 128 lanes) and one head's [CG, C]
+    # score/probability temporaries.  Long chunks outgrow the 16 MiB
+    # default; v5e has 128 MiB of VMEM.
+    qb = kvh * cg * d * q.dtype.itemsize
+    vmem = (4 * qb + 4 * c * kvh * d * k_new.dtype.itemsize
+            + kvh * cg * (d + 2 * 128) * 4 + 4 * cg * max(c, 128) * 4)
+    vmem_limit = int(min(max(2 * vmem, 32 << 20), 100 << 20))
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -484,7 +519,8 @@ def flash_prefill_kernel(
         compiler_params=compat.CompilerParams(
             # b is sequential: masked rows share the null block's out
             # window, so the batch axis must not race across cores.
-            dimension_semantics=("arbitrary", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
         ),
         input_output_aliases=aliases,
         interpret=interpret,

@@ -1,4 +1,8 @@
 import os
+# A host-only compile tool: pin the CPU platform (for this process and the
+# per-cell children, which inherit the environment) so no dry-run process
+# ever takes an accelerator, and ask for 512 virtual devices.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST precede every other import: jax locks the device count on first init.
 
@@ -271,6 +275,7 @@ def main(argv=None):
                    "--arch", arch, "--shape", shape_name,
                    "--mesh", mesh_kind, "--quant", args.quant,
                    "--out", args.out]
+            # The child inherits JAX_PLATFORMS=cpu (set at the top).
             return subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                     stderr=subprocess.PIPE, text=True)
 

@@ -16,16 +16,22 @@ continuous-batching engine (serve/server.py): paged KV pool of --kv-blocks
 x --block-size tokens, up to --max-batch concurrent requests, decode in
 jitted segments of --segment-len steps (single-device data path for now;
 --batch is the number of requests in the stream).
+
+The arch runs at the reduced smoke widths unless --full-width is given.
+--devices is the virtual-device count when JAX_PLATFORMS=cpu; on an
+accelerator the mesh spans the devices present, and a --mesh-shape that
+does not fit them is re-derived (runtime.fit_mesh_shape).  The functions
+below main() are the entry points chip_smoke.py drives.
 """
 import argparse
-import os
 import time
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=8,
+                    help="virtual CPU devices (JAX_PLATFORMS=cpu only)")
     ap.add_argument("--mesh-shape", default="4,2")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -110,157 +116,129 @@ def main():
                     help="continuous: wrap each jitted dispatch in a "
                     "jax.profiler.TraceAnnotation named after its engine "
                     "span (for captured device profiles)")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the arch at its published widths instead "
+                    "of the reduced smoke config")
     args = ap.parse_args()
 
-    if "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro import compat
+    from repro.launch import runtime
+    runtime.force_host_devices(args.devices)
+    runtime.enable_compile_cache()
 
     from repro import configs as cfg_lib
-    from repro.distributed import sharding as shard_lib
+    from repro.core import backend as backend_lib
     from repro.models import model as M
 
-    from repro.core import backend as backend_lib
-
-    cfg = cfg_lib.reduced_config(args.arch)
-    params = M.init(jax.random.PRNGKey(0), cfg)
-    pspec = M.pspec(cfg)
+    cfg = (cfg_lib.get_config(args.arch) if args.full_width
+           else cfg_lib.reduced_config(args.arch))
     plan = None
     if args.plan is not None:
         plan = backend_lib.load_plan(args.plan)
     elif args.quant == "w8a8":
         plan = M.DEFAULT_DEPLOY_PLAN
-    if plan is not None:
-        params = M.freeze_params(params, a_scale=0.05, plan=plan)
-        pspec = M.freeze_pspec(pspec, plan=plan)
+    tag = "plan" if args.plan is not None else args.quant
 
-    if args.continuous:
-        # Continuous batching: paged KV pool + request scheduler (single
-        # device; the pjit'd mesh path below remains the static engine).
-        import numpy as np
-
-        from repro.serve import ContinuousEngine, Request
-
-        from repro.serve import RequestStatus
-
-        ce = ContinuousEngine(
-            params, cfg, plan=plan, max_batch=args.max_batch,
-            kv_blocks=args.kv_blocks, block_size=args.block_size,
-            segment_len=args.segment_len, paged_attn=args.paged_attn,
-            chunked_prefill=args.chunked_prefill,
-            prefill_chunk=args.prefill_chunk,
-            preemption=args.preemption, max_queue=args.max_queue,
-            prefix_cache=args.prefix_cache,
-            snapshot_dir=args.snapshot_dir,
-            snapshot_interval=args.snapshot_interval,
-            telemetry=not args.no_telemetry,
-            profiler_annotations=args.profiler_annotations)
-        if args.restore is not None:
-            # Cold start from a checkpoint: no synthetic stream — serve
-            # whatever the snapshot holds in flight to completion.
-            t0 = time.perf_counter()
-            res = ce.restore(args.restore).resume()
-            reqs = list(res.values())
-            dt = time.perf_counter() - t0
-        else:
-            rng = np.random.default_rng(0)
-            arrivals = np.cumsum(rng.poisson(2.0, size=args.batch))
-            sys_prefix = None
-            if args.prefix_cache:
-                # Shared system prefix covering ~half the prompt so cache
-                # hits actually occur on 80% of the stream.
-                n_sys = max(args.block_size,
-                            (args.prompt_len // 2) // args.block_size
-                            * args.block_size)
-                sys_prefix = rng.integers(0, cfg.vocab, n_sys)
-            reqs = []
-            for i, t in enumerate(arrivals):
-                prompt = rng.integers(0, cfg.vocab, args.prompt_len)
-                if sys_prefix is not None and rng.random() < 0.8:
-                    prompt = np.concatenate(
-                        [sys_prefix, prompt[len(sys_prefix):]])
-                reqs.append(
-                    Request(rid=i, prompt=prompt, max_new=args.tokens,
-                            arrival_step=int(t),
-                            deadline_steps=args.deadline_steps))
-            t0 = time.perf_counter()
-            if args.drain_deadline is not None:
-                # Graceful-shutdown demo: latch the drain at the first
-                # completion — admissions close, in-flight requests get
-                # the deadline, stragglers spill into the final snapshot
-                # (serve them later with --restore).
-                res, latched = {}, False
-                for ev in ce.run_stream(reqs):
-                    if ev["event"] == "finish":
-                        res[ev["rid"]] = ev["result"]
-                        if not latched:
-                            ce.drain(args.drain_deadline)
-                            latched = True
-                if not latched:
-                    raise SystemExit("--drain-deadline: no request "
-                                     "finished before the drain could "
-                                     "latch; raise --tokens")
-            else:
-                res = ce.run(reqs)
-            dt = time.perf_counter() - t0
-        total = sum(len(r.tokens) for r in res.values())
-        n_ok = sum(r.status is RequestStatus.OK for r in res.values())
-        lat = sorted(r.latency_steps for r in res.values()
-                     if r.admitted_step >= 0) or [0]
-        tag = "plan" if args.plan is not None else args.quant
-        attn = "paged-attn" if args.paged_attn else "gather"
-        pf = (f"chunked-prefill:{ce.prefill_chunk}" if args.chunked_prefill
-              else "blocking-prefill")
-        if args.prefix_cache:
-            pf += "|prefix-cache"
-        print(f"[{tag}|continuous|{attn}|{pf}|preemption:{args.preemption}] "
-              f"served {len(reqs)} requests "
-              f"/ {total} tokens in {dt:.2f}s ({total/dt:.1f} tok/s incl. "
-              f"compile); {ce.last_run_segments} segments, "
-              f"{ce.last_run_dispatches} dispatches, "
-              f"{ce.last_run_host_syncs} host syncs, "
-              f"{ce.last_run_defrags} defrags, "
-              f"{n_ok}/{len(reqs)} OK ({ce.last_run_preemptions} preempts, "
-              f"{ce.last_run_recomputes} recomputes, "
-              f"{ce.last_run_spills} SPILLED / {ce.last_run_restores} "
-              f"restored ({ce.last_run_spill_bytes} spill bytes), "
-              f"{ce.last_run_snapshots} snapshots, "
-              f"{ce.last_run_recoveries} RECOVERED, "
-              f"{ce.last_run_sheds} shed, {ce.last_run_timeouts} timeout), "
-              f"{ce.last_run_prefix_hits} prefix hits "
-              f"({ce.last_run_prefix_hit_tokens} tok cached, "
-              f"{ce.last_run_prefix_misses} misses, "
-              f"{ce.last_run_cow_copies} CoW, "
-              f"{ce.last_run_suffix_prefills} suffix prefills), "
-              f"p50 latency {lat[len(lat)//2]} steps, TTFT p99 "
-              f"{ce.ttft_percentile(99)*1e3:.1f}ms, peak pool occupancy "
-              f"{max((o for _, o in ce.occupancy_trace), default=0.0):.2f}")
-        if ce.last_snapshot_path:
-            print(f"snapshot -> {ce.last_snapshot_path}")
-        if args.metrics_out:
-            ce.export_metrics(args.metrics_out)
-            print(f"metrics -> {args.metrics_out}")
-        if args.trace_out:
-            ce.export_trace(args.trace_out)
-            print(f"trace -> {args.trace_out} (open in https://ui.perfetto."
-                  "dev or chrome://tracing)")
+    if not args.continuous:
+        import jax
+        shape = runtime.fit_mesh_shape(
+            tuple(int(x) for x in args.mesh_shape.split(",")),
+            len(jax.devices()))
+        mesh = mesh_for(shape)
+        param_sh = param_shardings(cfg, plan, mesh)
+        params = build_params(cfg, plan, shardings=param_sh)
+        prompts = jax.random.randint(
+            jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0,
+            cfg.vocab)
+        t0 = time.perf_counter()
+        generate_on_mesh(params, cfg, plan, mesh, param_sh, prompts,
+                         args.tokens)
+        dt = time.perf_counter() - t0
+        total = args.batch * args.tokens
+        print(f"[{tag}] served {total} tokens on {mesh.size} devices "
+              f"(mesh {dict(mesh.shape)}) in {dt:.2f}s "
+              f"({total/dt:.1f} tok/s incl. compile)")
         return
 
-    shape = tuple(int(x) for x in args.mesh_shape.split(","))
+    params = build_params(cfg, plan)
+    reqs = synthetic_requests(cfg, n=args.batch, prompt_len=args.prompt_len,
+                              max_new=args.tokens,
+                              deadline_steps=args.deadline_steps,
+                              shared_prefix=args.prefix_cache,
+                              block_size=args.block_size)
+    ce, res, dt = serve_continuous(
+        params, cfg, reqs, plan=plan, restore=args.restore,
+        drain_deadline=args.drain_deadline, max_batch=args.max_batch,
+        kv_blocks=args.kv_blocks, block_size=args.block_size,
+        segment_len=args.segment_len, paged_attn=args.paged_attn,
+        chunked_prefill=args.chunked_prefill,
+        prefill_chunk=args.prefill_chunk, preemption=args.preemption,
+        max_queue=args.max_queue, prefix_cache=args.prefix_cache,
+        snapshot_dir=args.snapshot_dir,
+        snapshot_interval=args.snapshot_interval,
+        telemetry=not args.no_telemetry,
+        profiler_annotations=args.profiler_annotations)
+    print(continuous_report(ce, res, dt, tag))
+    if ce.last_snapshot_path:
+        print(f"snapshot -> {ce.last_snapshot_path}")
+    if args.metrics_out:
+        ce.export_metrics(args.metrics_out)
+        print(f"metrics -> {args.metrics_out}")
+    if args.trace_out:
+        ce.export_trace(args.trace_out)
+        print(f"trace -> {args.trace_out} (open in https://ui.perfetto."
+              "dev or chrome://tracing)")
+
+
+def build_params(cfg, plan, *, seed: int = 0, a_scale: float = 0.05,
+                 shardings=None):
+    """Seeded random weights for `cfg`, frozen by `plan` (None: float
+    master).  A frozen model is built group by group
+    (:func:`repro.models.model.init_frozen`), so the float master of a
+    full-width model never has to fit on the device next to its int8
+    copy."""
+    import jax
+
+    from repro.models import model as M
+    key = jax.random.PRNGKey(seed)
+    if plan is not None:
+        return M.init_frozen(key, cfg, a_scale=a_scale, plan=plan,
+                             shardings=shardings)
+    params = M.init(key, cfg)
+    if shardings is not None:
+        params = jax.device_put(params, shardings)
+    return params
+
+
+def mesh_for(shape, devices=None):
+    """A ``(data, model)`` (or ``(pod, data, model)``) Auto-axis mesh."""
+    from repro import compat
     axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-    mesh = jax.make_mesh(shape, axes)
-    param_sh = shard_lib.resolve_param_specs(pspec, mesh)
-    params = jax.tree.map(jax.device_put, params, param_sh)
+    return compat.make_mesh(shape, axes, devices=devices)
 
-    max_len = args.prompt_len + args.tokens + 8
-    prompts = {"tokens": jax.random.randint(
-        jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, cfg.vocab)}
 
+def param_shardings(cfg, plan, mesh):
+    """Per-leaf NamedShardings of the (frozen, when `plan` deploys int8)
+    params on `mesh`, from the model's logical-axes rules."""
+    from repro.distributed import sharding as shard_lib
+    from repro.models import model as M
+    pspec = M.pspec(cfg)
+    if plan is not None:
+        pspec = M.freeze_pspec(pspec, plan=plan)
+    return shard_lib.resolve_param_specs(pspec, mesh)
+
+
+def generate_on_mesh(params, cfg, plan, mesh, param_sh, prompts, n_tokens):
+    """Static greedy generation on a device mesh: pjit'd prefill, then
+    ``n_tokens - 1`` decode steps.  Returns ``(tokens [B, n_tokens],
+    first-step logits [B, cfg.vocab])`` as host arrays (the vocab padding
+    columns dropped)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro import compat
+    from repro.models import model as M
+    max_len = prompts.shape[1] + n_tokens + 8
     with compat.set_mesh(mesh):
         prefill = jax.jit(
             lambda p, b: M.prefill(p, b, cfg, max_len=max_len, mode=plan),
@@ -268,20 +246,114 @@ def main():
         decode = jax.jit(lambda p, b, c: M.decode_step(p, b, c, cfg,
                                                        mode=plan),
                          in_shardings=(param_sh, None, None))
-        t0 = time.perf_counter()
-        logits, caches = prefill(params, prompts)
-        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+        logits, caches = prefill(params, {"tokens": prompts})
+        first = logits[:, -1]
+        tok = jnp.argmax(first, -1).astype(jnp.int32)
         out = [tok]
-        for _ in range(args.tokens - 1):
+        for _ in range(n_tokens - 1):
             logits, caches = decode(params, {"tokens": tok[:, None]}, caches)
             tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
             out.append(tok)
-        jax.block_until_ready(out[-1])
-        dt = time.perf_counter() - t0
-    total = args.batch * args.tokens
-    tag = "plan" if args.plan is not None else args.quant
-    print(f"[{tag}] served {total} tokens on {args.devices} devices "
-          f"in {dt:.2f}s ({total/dt:.1f} tok/s incl. compile)")
+        toks = jax.block_until_ready(jnp.stack(out, axis=1))
+    return (np.asarray(toks),
+            np.asarray(first.astype(jnp.float32))[:, :cfg.vocab])
+
+
+def synthetic_requests(cfg, *, n: int, prompt_len: int, max_new: int,
+                       deadline_steps=None, shared_prefix: bool = False,
+                       block_size: int = 16, seed: int = 0):
+    """The launcher's seeded Poisson request stream; with `shared_prefix`
+    80% of the prompts start with one common system prefix (about half
+    the prompt) so prefix-cache hits actually occur."""
+    import numpy as np
+
+    from repro.serve import Request
+    rng = np.random.default_rng(seed)
+    arrivals = np.cumsum(rng.poisson(2.0, size=n))
+    sys_prefix = None
+    if shared_prefix:
+        n_sys = max(block_size, (prompt_len // 2) // block_size * block_size)
+        sys_prefix = rng.integers(0, cfg.vocab, n_sys)
+    reqs = []
+    for i, t in enumerate(arrivals):
+        prompt = rng.integers(0, cfg.vocab, prompt_len)
+        if sys_prefix is not None and rng.random() < 0.8:
+            prompt = np.concatenate([sys_prefix, prompt[len(sys_prefix):]])
+        reqs.append(Request(rid=i, prompt=prompt, max_new=max_new,
+                            arrival_step=int(t),
+                            deadline_steps=deadline_steps))
+    return reqs
+
+
+def serve_continuous(params, cfg, requests, *, plan=None, restore=None,
+                     drain_deadline=None, **engine_kw):
+    """Serve `requests` through a :class:`~repro.serve.ContinuousEngine`
+    built with `engine_kw`.  Returns ``(engine, {rid: RequestResult},
+    wall seconds)``.
+
+    `restore` cold-starts from a snapshot file instead (the snapshot's
+    in-flight requests are served to completion; `requests` is ignored).
+    `drain_deadline` latches a graceful drain at the first completion:
+    admissions close, in-flight requests get that many sim steps, and
+    stragglers spill into a final snapshot."""
+    from repro.serve import ContinuousEngine
+    ce = ContinuousEngine(params, cfg, plan=plan, **engine_kw)
+    t0 = time.perf_counter()
+    if restore is not None:
+        res = ce.restore(restore).resume()
+    elif drain_deadline is not None:
+        res, latched = {}, False
+        for ev in ce.run_stream(requests):
+            if ev["event"] == "finish":
+                res[ev["rid"]] = ev["result"]
+                if not latched:
+                    ce.drain(drain_deadline)
+                    latched = True
+        if not latched:
+            raise SystemExit("--drain-deadline: no request finished before "
+                             "the drain could latch; raise --tokens")
+    else:
+        res = ce.run(requests)
+    return ce, res, time.perf_counter() - t0
+
+
+def continuous_report(ce, res, dt, tag) -> str:
+    """One-line summary of a continuous run."""
+    from repro.core import backend as backend_lib
+    from repro.serve import RequestStatus
+    total = sum(len(r.tokens) for r in res.values())
+    n_ok = sum(r.status is RequestStatus.OK for r in res.values())
+    lat = sorted(r.latency_steps for r in res.values()
+                 if r.admitted_step >= 0) or [0]
+    attn = ("paged-attn" if backend_lib.paged_attn_enabled(ce.plan)
+            else "gather")
+    pf = (f"chunked-prefill:{ce.prefill_chunk}" if ce.chunked_prefill
+          else "blocking-prefill")
+    if ce.prefix_cache:
+        pf += "|prefix-cache"
+    return (
+        f"[{tag}|continuous|{attn}|{pf}|preemption:{ce.preemption}] "
+        f"served {len(res)} requests "
+        f"/ {total} tokens in {dt:.2f}s ({total/dt:.1f} tok/s incl. "
+        f"compile); {ce.last_run_segments} segments, "
+        f"{ce.last_run_dispatches} dispatches, "
+        f"{ce.last_run_host_syncs} host syncs, "
+        f"{ce.last_run_defrags} defrags, "
+        f"{n_ok}/{len(res)} OK ({ce.last_run_preemptions} preempts, "
+        f"{ce.last_run_recomputes} recomputes, "
+        f"{ce.last_run_spills} SPILLED / {ce.last_run_restores} "
+        f"restored ({ce.last_run_spill_bytes} spill bytes), "
+        f"{ce.last_run_snapshots} snapshots, "
+        f"{ce.last_run_recoveries} RECOVERED, "
+        f"{ce.last_run_sheds} shed, {ce.last_run_timeouts} timeout), "
+        f"{ce.last_run_prefix_hits} prefix hits "
+        f"({ce.last_run_prefix_hit_tokens} tok cached, "
+        f"{ce.last_run_prefix_misses} misses, "
+        f"{ce.last_run_cow_copies} CoW, "
+        f"{ce.last_run_suffix_prefills} suffix prefills), "
+        f"p50 latency {lat[len(lat)//2]} steps, TTFT p99 "
+        f"{ce.ttft_percentile(99)*1e3:.1f}ms, peak pool occupancy "
+        f"{max((o for _, o in ce.occupancy_trace), default=0.0):.2f}")
 
 
 if __name__ == "__main__":

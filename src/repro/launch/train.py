@@ -1,23 +1,23 @@
 """Production training launcher: pjit'd train step on the production mesh.
 
 On a real TPU fleet this binary runs per host (jax.distributed.initialize
-picks up the pod topology from the environment); on this CPU box it drives
-the same code on forced host devices for small configs — the dry-run proves
-the full-size lowering (launch/dryrun.py).
+picks up the pod topology from the environment); with JAX_PLATFORMS=cpu it
+drives the same code on --devices virtual host devices for small configs —
+the dry-run proves the full-size lowering (launch/dryrun.py).
 
 Usage:
   python -m repro.launch.train --arch granite-moe-1b-a400m --steps 20 \
       --devices 8 --mesh-shape 4,2 [--reduced]
 """
 import argparse
-import os
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=8,
+                    help="virtual CPU devices (JAX_PLATFORMS=cpu only)")
     ap.add_argument("--mesh-shape", default="4,2")
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--batch", type=int, default=8)
@@ -28,9 +28,9 @@ def main():
                          "or a JSON file) routed through the backend registry")
     args = ap.parse_args()
 
-    if "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch import runtime
+    runtime.force_host_devices(args.devices)
+    runtime.enable_compile_cache()
 
     import jax
 
@@ -46,9 +46,10 @@ def main():
     from repro.train import optimizer as opt_lib
     from repro.train.train_loop import make_train_step
 
-    shape = tuple(int(x) for x in args.mesh_shape.split(","))
+    shape = runtime.fit_mesh_shape(
+        tuple(int(x) for x in args.mesh_shape.split(",")), len(jax.devices()))
     axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-    mesh = jax.make_mesh(shape, axes)
+    mesh = compat.make_mesh(shape, axes)
 
     cfg = cfg_lib.reduced_config(args.arch) if args.reduced \
         else cfg_lib.get_config(args.arch)
@@ -80,6 +81,7 @@ def main():
     step_fn = make_train_step(cfg, tcfg, plan=plan)
     with compat.set_mesh(mesh):
         jstep = jax.jit(step_fn, in_shardings=(param_sh, opt_sh, None),
+                        out_shardings=(param_sh, opt_sh, None),
                         donate_argnums=(0, 1))
         for step in range(start, args.steps):
             batch = synthetic.lm_batch(stream, step)

@@ -17,7 +17,9 @@ Batch layout (keys present depend on arch/frontend):
 """
 from __future__ import annotations
 
+import concurrent.futures
 import functools
+import os
 from typing import Any
 
 import jax
@@ -131,6 +133,69 @@ def freeze_params(params, a_scale: float = 1.0, plan=None):
         return node
 
     return walk("", params)
+
+
+def frozen_groups(shapes) -> list[tuple[tuple, int]]:
+    """``(path, bytes)`` of every parameter group of a (frozen) parameter
+    tree: each dict whose values are all arrays, e.g. one stacked linear's
+    ``w_q``/``w_scale``/``a_scale``."""
+    groups: list[tuple[tuple, int]] = []
+
+    def collect(path, node):
+        if isinstance(node, dict) and any(isinstance(v, dict)
+                                          for v in node.values()):
+            for k, v in node.items():
+                collect(path + (k,), v)
+            return
+        groups.append((path, sum(int(x.size) * x.dtype.itemsize
+                                 for x in jax.tree.leaves(node))))
+
+    collect((), shapes)
+    return groups
+
+
+def init_frozen(key, cfg, *, a_scale: float = 1.0, plan=None,
+                shardings=None):
+    """``freeze_params(init(key, cfg), a_scale, plan)`` without the float
+    master ever existing whole on the device.
+
+    One jitted program per parameter group (a dict of array leaves, e.g.
+    one stacked linear's ``w_q``/``w_scale``/``a_scale``): XLA's dead-code
+    elimination keeps only that group's slice of the seeded init, so the
+    device peak is the frozen model built so far plus one group's float
+    master.  Values equal the jitted two-step form.  Largest groups go
+    first, while the device is still emptiest.  `shardings`
+    (optional) is a tree of shardings matching the frozen structure."""
+
+    def full(k):
+        return freeze_params(init(k, cfg), a_scale=a_scale, plan=plan)
+
+    shapes = jax.eval_shape(full, key)
+    groups = frozen_groups(shapes)
+
+    def get(tree, path):
+        for k in path:
+            tree = tree[k]
+        return tree
+
+    def program(path):
+        sh = None if shardings is None else get(shardings, path)
+        return jax.jit(lambda k: get(full(k), path),
+                       out_shardings=sh).lower(key).compile()
+
+    # Compiling is host work outside the GIL: compile every group's program
+    # at once (one full-width group takes ~16 s alone), then run them.
+    order = [path for path, _ in sorted(groups, key=lambda g: -g[1])]
+    with concurrent.futures.ThreadPoolExecutor(
+            min(8, os.cpu_count() or 1)) as pool:
+        programs = list(pool.map(program, order))
+    out: dict = {}
+    for path, prog in zip(order, programs):
+        node = out
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = prog(key)
+    return out
 
 
 def freeze_pspec(pspec_tree, plan=None):

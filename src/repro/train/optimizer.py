@@ -23,7 +23,9 @@ def cosine_schedule(step, lr: float, warmup: int, total: int):
 
 
 def init_opt_state(params) -> dict:
-    f32 = lambda p: p.astype(jnp.float32)
+    # A copy even for f32 params: the master must not alias the params,
+    # which a train step donates alongside it.
+    f32 = lambda p: jnp.array(p, jnp.float32, copy=True)
     zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
     return {
         "master": jax.tree.map(f32, params),

@@ -146,3 +146,19 @@ def test_mrope_positions_change_output(rng):
     h1, _ = M.forward(params, {"embeds": emb, "positions": pos1}, cfg)
     h2, _ = M.forward(params, {"embeds": emb, "positions": pos2}, cfg)
     assert not np.allclose(np.asarray(h1), np.asarray(h2))
+
+
+@pytest.mark.parametrize("arch", ["dense", "moe"])
+def test_init_frozen_matches_frozen_init(arch):
+    """Group-by-group frozen init == the jitted init-then-freeze program,
+    leaf for leaf (dense linears and MoE expert banks)."""
+    cfg = ARCHS[arch]
+    key = jax.random.PRNGKey(3)
+    plan = M.DEFAULT_DEPLOY_PLAN
+    want = jax.jit(lambda k: M.freeze_params(M.init(k, cfg), a_scale=0.05,
+                                             plan=plan))(key)
+    got = M.init_frozen(key, cfg, a_scale=0.05, plan=plan)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
