@@ -1,0 +1,80 @@
+"""Operations and bytes of the work, from shapes alone.
+
+The least time the chip could take for a piece of work is the larger of
+its operations over the peak rate and its bytes over the HBM bandwidth
+(``peaks.json``).  A kernel's roofline share is that least time over the
+kernel's device time; ``mfu`` is the model's operations over the window
+times the int8 peak.
+"""
+from __future__ import annotations
+
+import re
+
+_SHAPE = re.compile(r"\b(s8|u8|s32|f32|bf16|f16|pred|s16|u32)\[([\d,]*)\]")
+_BYTES = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "s32": 4, "u32": 4, "f32": 4}
+
+
+def hlo_shapes(text: str) -> list[tuple[str, tuple[int, ...]]]:
+    """``(dtype, dims)`` of every array shape in an HLO instruction's
+    text, the result first and then the operands in order."""
+    out = []
+    for dt, dims in _SHAPE.findall(text or ""):
+        out.append((dt, tuple(int(d) for d in dims.split(",") if d)))
+    return out
+
+
+def _size(shape) -> int:
+    n = 1
+    for d in shape[1]:
+        n *= d
+    return n * _BYTES[shape[0]]
+
+
+def w8a8_call(text: str) -> tuple[float, float] | None:
+    """(operations, bytes) of one ``cim_w8a8_matmul`` call from its HLO
+    text: the int8 weight [K, N], the activation [M, K] (int8 or float),
+    the per-channel scale and bias [N], and the result [M, N]."""
+    shapes = hlo_shapes(text)
+    if len(shapes) < 3:
+        return None
+    out, operands = shapes[0], shapes[1:]
+    w = [s for s in operands if s[0] == "s8" and len(s[1]) == 2]
+    if len(out[1]) != 2 or not w:
+        return None
+    m, n = out[1]
+    w = max(w, key=lambda s: s[1][0] * s[1][1])
+    k = w[1][0]
+    if w[1][1] != n:
+        return None
+    a = next((s for s in operands if s is not w and len(s[1]) == 2
+              and s[1] == (m, k)), None)
+    if a is None:
+        return None
+    scales = sum(_size(s) for s in operands if len(s[1]) <= 2
+                 and s is not w and s is not a)
+    return 2.0 * m * k * n, float(_size(w) + _size(a) + _size(out) + scales)
+
+
+def least_seconds(ops: float, nbytes: float, op_peak: float,
+                  bw: float) -> float:
+    return max(ops / op_peak, nbytes / bw)
+
+
+def matmul_params(cfg: dict) -> tuple[int, int]:
+    """(weights of one token's pass through the layers, of the head)."""
+    d, f = int(cfg["hidden_size"]), int(cfg["intermediate_size"])
+    h, kvh = int(cfg["num_attention_heads"]), int(cfg["num_key_value_heads"])
+    hd = int(cfg["head_dim"])
+    per_layer = d * h * hd * 2 + d * kvh * hd * 2 + 3 * d * f
+    return per_layer * int(cfg["num_hidden_layers"]), \
+        d * int(cfg["vocab_size"])
+
+
+def model_ops(cfg: dict, tokens: int, heads_out: int, ctx_sum: int) -> float:
+    """Operations of `tokens` token positions through the model, `heads_out`
+    of them through the output head, attending `ctx_sum` keys in all."""
+    layers_w, head_w = matmul_params(cfg)
+    attn = 4.0 * int(cfg["num_hidden_layers"]) \
+        * int(cfg["num_attention_heads"]) * int(cfg["head_dim"]) * ctx_sum
+    return 2.0 * layers_w * tokens + 2.0 * head_w * heads_out + attn
