@@ -113,9 +113,12 @@ def main():
                     "(registry counters stay live; the token stream is "
                     "identical either way)")
     ap.add_argument("--profiler-annotations", action="store_true",
-                    help="continuous: wrap each jitted dispatch in a "
-                    "jax.profiler.TraceAnnotation named after its engine "
-                    "span (for captured device profiles)")
+                    help="continuous: also open every engine span as a "
+                    "jax.profiler.TraceAnnotation serve/<span> (round "
+                    "phases schedule, inputs, harvest, emit; dispatches "
+                    "decode_segment, mixed_segment, prefill, ...; "
+                    "cow_copy, defrag, ...), on the device ops' clock in "
+                    "a captured profile")
     ap.add_argument("--full-width", action="store_true",
                     help="serve the arch at its published widths instead "
                     "of the reduced smoke config")

@@ -476,9 +476,12 @@ def attention(
             and xattn_cache is None):
         x_in = backend_lib.shared_quant((p["q"], p["k"], p["v"]), x)
 
-    q = layers.dense(p["q"], x_in, mode, dtype=dt,
-                     path="attn/q").reshape(b, s, cfg.n_heads, hd)
-    q = constrain(q, {0: "batch", 2: "model"})
+    # Named scopes (qkv, kv_write, flash_decode / flash_prefill, o_proj)
+    # are profile metadata only: they leave every op and kernel name as is.
+    with jax.named_scope("qkv"):
+        q = layers.dense(p["q"], x_in, mode, dtype=dt,
+                         path="attn/q").reshape(b, s, cfg.n_heads, hd)
+        q = constrain(q, {0: "batch", 2: "model"})
 
     if xattn_cache is not None:
         # Cross-attention against precomputed (frozen) encoder K/V.
@@ -492,38 +495,40 @@ def attention(
                          path="attn/o")
         return y.astype(dt), None
 
-    kv_src = xattn_kv if xattn_kv is not None else x_in
-    sk = kv_src.shape[1]
-    k = layers.dense(p["k"], kv_src, mode, dtype=dt,
-                     path="attn/k").reshape(b, sk, cfg.n_kv_heads, hd)
-    v = layers.dense(p["v"], kv_src, mode, dtype=dt,
-                     path="attn/v").reshape(b, sk, cfg.n_kv_heads, hd)
-    k = constrain(k, {0: "batch", 2: "model"})
-    v = constrain(v, {0: "batch", 2: "model"})
+    with jax.named_scope("qkv"):
+        kv_src = xattn_kv if xattn_kv is not None else x_in
+        sk = kv_src.shape[1]
+        k = layers.dense(p["k"], kv_src, mode, dtype=dt,
+                         path="attn/k").reshape(b, sk, cfg.n_kv_heads, hd)
+        v = layers.dense(p["v"], kv_src, mode, dtype=dt,
+                         path="attn/v").reshape(b, sk, cfg.n_kv_heads, hd)
+        k = constrain(k, {0: "batch", 2: "model"})
+        v = constrain(v, {0: "batch", 2: "model"})
 
-    if "q_norm" in p:
-        q = layers.rmsnorm(p["q_norm"], q, cfg.norm_eps)
-        k = layers.rmsnorm(p["k_norm"], k, cfg.norm_eps)
+        if "q_norm" in p:
+            q = layers.rmsnorm(p["q_norm"], q, cfg.norm_eps)
+            k = layers.rmsnorm(p["k_norm"], k, cfg.norm_eps)
 
-    if xattn_kv is None:  # self-attention: rotary
-        if positions is None:
-            # Keep batch dim 1: the angles are batch-invariant and XLA then
-            # hoists a [1, S, hd/2] constant instead of a replicated
-            # [B_global, S, hd/2] buffer.
-            base = jnp.arange(s)[None, :]
-            if kv_cache is not None:
-                if "lens" in kv_cache:
-                    # Paged pool: per-request lengths -> per-row positions.
-                    base = base + kv_cache["lens"][:, None]
-                else:
-                    base = base + kv_cache["len"]
-            positions = base
-            if cfg.mrope_sections is not None:
-                positions = jnp.broadcast_to(positions[None], (3, 1, s))
-        ang_q = layers.rope_angles(positions, hd, cfg.rope_theta,
-                                   cfg.mrope_sections)
-        q = layers.apply_rope(q, ang_q)
-        k = layers.apply_rope(k, ang_q)
+        if xattn_kv is None:  # self-attention: rotary
+            if positions is None:
+                # Keep batch dim 1: the angles are batch-invariant and XLA
+                # then hoists a [1, S, hd/2] constant instead of a
+                # replicated [B_global, S, hd/2] buffer.
+                base = jnp.arange(s)[None, :]
+                if kv_cache is not None:
+                    if "lens" in kv_cache:
+                        # Paged pool: per-request lengths -> per-row
+                        # positions.
+                        base = base + kv_cache["lens"][:, None]
+                    else:
+                        base = base + kv_cache["len"]
+                positions = base
+                if cfg.mrope_sections is not None:
+                    positions = jnp.broadcast_to(positions[None], (3, 1, s))
+            ang_q = layers.rope_angles(positions, hd, cfg.rope_theta,
+                                       cfg.mrope_sections)
+            q = layers.apply_rope(q, ang_q)
+            k = layers.apply_rope(k, ang_q)
 
     new_cache = None
     if kv_cache is not None and "block_tables" in kv_cache:
@@ -552,41 +557,51 @@ def attention(
             n_tok = kv_cache["chunk_len"]
             impl = ("fused" if backend_lib.paged_attn_enabled(mode)
                     else "reference")
-            out, k_pages, v_pages = attend_prefill_paged(
-                q, k, v, kv_cache["k"], kv_cache["v"], bt, lens, n_tok,
-                wm, impl=impl,
-                has_past=kv_cache.get("pf_has_past", True))
-            y = layers.dense(p["o"], out.reshape(b, s, cfg.n_heads * hd),
-                             mode, path="attn/o")
+            with jax.named_scope("flash_prefill"):
+                out, k_pages, v_pages = attend_prefill_paged(
+                    q, k, v, kv_cache["k"], kv_cache["v"], bt, lens, n_tok,
+                    wm, impl=impl,
+                    has_past=kv_cache.get("pf_has_past", True))
+            with jax.named_scope("o_proj"):
+                y = layers.dense(p["o"],
+                                 out.reshape(b, s, cfg.n_heads * hd),
+                                 mode, path="attn/o")
             return y.astype(dt), {"k": k_pages, "v": v_pages}
         k_pages, v_pages = kv_cache["k"], kv_cache["v"]
         int8_pool = isinstance(k_pages, quant_lib.QTensor)
         bs_blk = (k_pages.q if int8_pool else k_pages).shape[1]
-        slot = jnp.minimum(lens // bs_blk, bt.shape[1] - 1)
-        page = jnp.take_along_axis(bt, slot[:, None], axis=1)[:, 0]
-        off = lens % bs_blk
-        if wm is not None:
-            page = jnp.where(wm, page, 0)
-        if int8_pool:
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            k_pages = k_pages.at_set(
-                (page, off), quant_lib.QTensor(k_q[:, 0], k_s[:, 0][..., None]))
-            v_pages = v_pages.at_set(
-                (page, off), quant_lib.QTensor(v_q[:, 0], v_s[:, 0][..., None]))
-        else:
-            k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-            v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
+        with jax.named_scope("kv_write"):
+            slot = jnp.minimum(lens // bs_blk, bt.shape[1] - 1)
+            page = jnp.take_along_axis(bt, slot[:, None], axis=1)[:, 0]
+            off = lens % bs_blk
+            if wm is not None:
+                page = jnp.where(wm, page, 0)
+            if int8_pool:
+                k_q, k_s = quantize_kv(k)
+                v_q, v_s = quantize_kv(v)
+                k_pages = k_pages.at_set(
+                    (page, off),
+                    quant_lib.QTensor(k_q[:, 0], k_s[:, 0][..., None]))
+                v_pages = v_pages.at_set(
+                    (page, off),
+                    quant_lib.QTensor(v_q[:, 0], v_s[:, 0][..., None]))
+            else:
+                k_pages = k_pages.at[page, off].set(
+                    k[:, 0].astype(k_pages.dtype))
+                v_pages = v_pages.at[page, off].set(
+                    v[:, 0].astype(v_pages.dtype))
         wrote = (jnp.ones_like(lens) if wm is None
                  else wm.astype(jnp.int32))
         # DeploymentPlan(paged_attn=True) routes through the fused
         # flash-decoding kernel; default stays the gather reference.
         impl = ("fused" if backend_lib.paged_attn_enabled(mode)
                 else "reference")
-        out = attend_decode_paged(q, k_pages, v_pages, bt, lens + wrote,
-                                  impl=impl)
-        y = layers.dense(p["o"], out.reshape(b, s, cfg.n_heads * hd), mode,
-                         path="attn/o")
+        with jax.named_scope("flash_decode"):
+            out = attend_decode_paged(q, k_pages, v_pages, bt, lens + wrote,
+                                      impl=impl)
+        with jax.named_scope("o_proj"):
+            y = layers.dense(p["o"], out.reshape(b, s, cfg.n_heads * hd),
+                             mode, path="attn/o")
         return y.astype(dt), {"k": k_pages, "v": v_pages}
     if kv_cache is not None:
         s_cache = kv_cache["k"].shape[1]

@@ -51,13 +51,16 @@ def dense_block_pspec(cfg, frozen=False) -> dict:
 
 def dense_block(p, x, cfg, *, cache=None, positions=None, causal=True,
                 mode=None):
-    h, new_cache = attn_lib.attention(
-        p["attn"], layers.rmsnorm(p["attn_norm"], x, cfg.norm_eps), cfg,
-        positions=positions, causal=causal, kv_cache=cache, mode=mode,
-    )
+    with jax.named_scope("attention"):
+        h, new_cache = attn_lib.attention(
+            p["attn"], layers.rmsnorm(p["attn_norm"], x, cfg.norm_eps), cfg,
+            positions=positions, causal=causal, kv_cache=cache, mode=mode,
+        )
     x = x + h
-    x = x + layers.mlp(p["mlp"], layers.rmsnorm(p["mlp_norm"], x, cfg.norm_eps),
-                       cfg.act, mode or cfg.linear_mode)
+    with jax.named_scope("mlp"):
+        x = x + layers.mlp(
+            p["mlp"], layers.rmsnorm(p["mlp_norm"], x, cfg.norm_eps),
+            cfg.act, mode or cfg.linear_mode)
     if getattr(cfg, "act_shard", False):
         from repro.distributed.sharding import constrain
         # residual stream stored d-sharded between blocks => remat carry
@@ -313,12 +316,14 @@ def decode_stack(params, x, cfg, caches: dict, *, positions=None, mode=None):
         def body(h, xs):
             blk_p, cache = xs
             kv = dict(cache, **shared)
-            if at == "dense":
-                h, nc = dense_block(blk_p, h, cfg, cache=kv,
-                                    positions=positions, mode=mode)
-            else:
-                h, nc, _ = moe_block(blk_p, h, cfg, cache=kv,
-                                     positions=positions, mode=mode)
+            # Profile metadata only: ops of one layer read layer/...
+            with jax.named_scope("layer"):
+                if at == "dense":
+                    h, nc = dense_block(blk_p, h, cfg, cache=kv,
+                                        positions=positions, mode=mode)
+                else:
+                    h, nc, _ = moe_block(blk_p, h, cfg, cache=kv,
+                                         positions=positions, mode=mode)
             return h, {key: nc[key] for key in cache}
 
         x, new_kv = jax.lax.scan(body, x, (params["blocks"], caches["kv"]))

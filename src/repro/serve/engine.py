@@ -141,18 +141,22 @@ class Engine:
             """decode + logprob-of-tok + next-token sample, all on device."""
             logits, caches = model_lib.decode_step(
                 params, {"tokens": tok[:, None]}, caches, cfg, mode=plan)
-            last = logits[:, -1]
-            if poison is not None:
-                last = jnp.where(poison[:, None], jnp.nan, last)
-            ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)), axis=-1)
-            lp = jax.nn.log_softmax(last.astype(jnp.float32))
-            lp_tok = jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
-            nxt = sample(last, rng, rids, t, temperature)
-            # Quarantined rows must still carry well-defined values through
-            # the jitted loop (NaN would propagate into buffers the caller
-            # keeps); the engine retracts their emission host-side.
-            nxt = jnp.where(ok, nxt, 0)
-            lp_tok = jnp.where(ok, lp_tok, 0.0)
+            with jax.named_scope("sample"):    # profile metadata only
+                last = logits[:, -1]
+                if poison is not None:
+                    last = jnp.where(poison[:, None], jnp.nan, last)
+                ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)),
+                             axis=-1)
+                lp = jax.nn.log_softmax(last.astype(jnp.float32))
+                lp_tok = jnp.take_along_axis(lp, tok[:, None],
+                                             axis=-1)[:, 0]
+                nxt = sample(last, rng, rids, t, temperature)
+                # Quarantined rows must still carry well-defined values
+                # through the jitted loop (NaN would propagate into buffers
+                # the caller keeps); the engine retracts their emission
+                # host-side.
+                nxt = jnp.where(ok, nxt, 0)
+                lp_tok = jnp.where(ok, lp_tok, 0.0)
             return nxt, lp_tok, ok, caches
 
         return step

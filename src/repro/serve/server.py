@@ -321,6 +321,9 @@ class ContinuousEngine:
         self._restored: _RunState | None = None
         self._at_boundary = False
         self._drain_req: tuple[int, str | None] | None = None
+        # {"round": segment index, "step": sim clock} of the scheduler
+        # round in progress: the args of every round-phase span.
+        self._round_args: dict = {}
         # All run accounting lives in ONE place: the telemetry registry
         # (counters/gauges/histograms) plus the tracer's event timeline.
         # The legacy `last_run_*` attributes are thin registry reads (see
@@ -389,14 +392,18 @@ class ContinuousEngine:
         self._cancel_req.add(rid)
 
     def _dispatch(self, fn, *args, name: str = "dispatch"):
+        # The enqueue of one jitted program: in a device profile the
+        # serve/<name> span sits on the device ops' clock, just before
+        # the program it launched (profiler-only: the Chrome trace has
+        # the segment span instead).
+        with self.telemetry.span(name, chrome=False, **self._round_args):
+            return self._launch(fn, *args)
+
+    def _launch(self, fn, *args):
+        """Call a jitted program, counted as one dispatch."""
         self.metrics.counter("serve_dispatches_total").inc()
         self.metrics.counter("serve_lifetime_dispatches_total").inc()
-        # Optional jax.profiler.TraceAnnotation scope: a device profile
-        # captured around run() shows each dispatch named after the engine
-        # span it belongs to, so profiler rows line up with the tracer's
-        # segment spans in perfetto.
-        with self.telemetry.annotate(f"serve/{name}"):
-            return fn(*args)
+        return fn(*args)
 
     # ------------------------------------------------------------------ jit
 
@@ -415,18 +422,19 @@ class ContinuousEngine:
         pf_len = kv_pool.blocks_for(bucket_len, self.block_size) \
             * self.block_size
 
-        def f(params, pages, tokens, length, block_table, rid, rng, t0,
-              temperature):
+        def serve_prefill(params, pages, tokens, length, block_table, rid,
+                          rng, t0, temperature):
             batch = {"tokens": tokens}
             if with_length:
                 batch["length"] = length
             logits, pages = model_lib.prefill_paged(
                 params, batch, cfg, pages=pages, block_table=block_table,
                 max_len=pf_len, mode=plan)
-            tok0 = sample(logits[:, -1], rng, rid, t0, temperature)
+            with jax.named_scope("sample"):
+                tok0 = sample(logits[:, -1], rng, rid, t0, temperature)
             return tok0, pages
 
-        fn = jax.jit(f)
+        fn = jax.jit(serve_prefill)
         self._fn_cache[key] = fn
         return fn
 
@@ -452,17 +460,18 @@ class ContinuousEngine:
         cfg = self.cfg
         sample = self.engine.make_sample(plan, greedy)
 
-        def f(params, pages, tokens, pos, n_tok, block_table, rid, rng, t0,
-              temperature):
+        def serve_suffix_prefill(params, pages, tokens, pos, n_tok,
+                                 block_table, rid, rng, t0, temperature):
             wm = jnp.asarray([not skip_write])
             logits0, pages = model_lib.prefill_chunk(
                 params, tokens, cfg, pages=pages, block_tables=block_table,
                 pos=pos, n_tok=n_tok, write_mask=wm, has_past=True,
                 mode=plan)
-            tok0 = sample(logits0, rng, rid, t0, temperature)
+            with jax.named_scope("sample"):
+                tok0 = sample(logits0, rng, rid, t0, temperature)
             return tok0, pages
 
-        fn = jax.jit(f)
+        fn = jax.jit(serve_suffix_prefill)
         self._fn_cache[key] = fn
         return fn
 
@@ -477,8 +486,9 @@ class ContinuousEngine:
         and is marked failed+done so the segment's remaining iterations
         mask it like any finished row.  The host quarantines failed rows
         as FAILED; their batch neighbors never see the NaN."""
-        def seg(params, pages, tables, tok, n_out, lens, done, failed,
-                rids, max_new, stops, poison, rng, temperature, pad_token):
+        def decode_loop(params, pages, tables, tok, n_out, lens, done,
+                        failed, rids, max_new, stops, poison, rng,
+                        temperature, pad_token):
             mb = tok.shape[0]
             out_t = jnp.full((mb, seg_len), pad_token, jnp.int32)
             out_lp = jnp.zeros((mb, seg_len), jnp.float32)
@@ -520,7 +530,7 @@ class ContinuousEngine:
                      failed, pages, out_t, out_lp))
             return pages, tok, n_out, lens, done, failed, out_t, out_lp, i
 
-        return seg
+        return decode_loop
 
     def _segment_fn(self, plan, greedy: bool, seg_len: int, stop_w: int):
         """ONE jitted dispatch: a pure decode segment.  Reuses the inner
@@ -531,14 +541,15 @@ class ContinuousEngine:
         loop = self._decode_loop(self.engine.make_step(plan, greedy),
                                  seg_len)
 
-        def seg(params, pages, tables, tok, n_out, lens, done, rids,
-                max_new, stops, poison, rng, temperature, pad_token):
+        def serve_decode_segment(params, pages, tables, tok, n_out, lens,
+                                 done, rids, max_new, stops, poison, rng,
+                                 temperature, pad_token):
             failed = jnp.zeros(done.shape, bool)
             return loop(params, pages, tables, tok, n_out, lens, done,
                         failed, rids, max_new, stops, poison, rng,
                         temperature, pad_token)
 
-        fn = jax.jit(seg)
+        fn = jax.jit(serve_decode_segment)
         self._fn_cache[key] = fn
         return fn
 
@@ -578,10 +589,11 @@ class ContinuousEngine:
         loop = self._decode_loop(self.engine.make_step(plan, greedy),
                                  seg_len)
 
-        def seg(params, pages, tables, pf_rows, pf_tables, pf_tok, pf_pos,
-                pf_cnt, pf_on, pf_nw, pf_fin, pf_t0, tok, n_out, lens,
-                done, rids, max_new, stops, poison, rng, temperature,
-                pad_token):
+        def serve_mixed_segment(params, pages, tables, pf_rows, pf_tables,
+                                pf_tok, pf_pos, pf_cnt, pf_on, pf_nw,
+                                pf_fin, pf_t0, tok, n_out, lens, done, rids,
+                                max_new, stops, poison, rng, temperature,
+                                pad_token):
             # pf_nw: rows whose chunk span is a CoW-copied block holding
             # byte-exact K/V already — compute logits, mask the write.
             logits0, pages = model_lib.prefill_chunk(
@@ -591,7 +603,9 @@ class ContinuousEngine:
             logits0 = jnp.where(poison[pf_rows][:, None], jnp.nan, logits0)
             ok0 = jnp.all(jnp.isfinite(logits0.astype(jnp.float32)),
                           axis=-1)
-            tok0 = sample(logits0, rng, rids[pf_rows], pf_t0, temperature)
+            with jax.named_scope("sample"):
+                tok0 = sample(logits0, rng, rids[pf_rows], pf_t0,
+                              temperature)
             fin = pf_on & pf_fin
             good = fin & ok0
             bad = fin & ~ok0
@@ -608,7 +622,7 @@ class ContinuousEngine:
                         failed, rids, max_new, stops, poison, rng,
                         temperature, pad_token)
 
-        fn = jax.jit(seg)
+        fn = jax.jit(serve_mixed_segment)
         self._fn_cache[key] = fn
         return fn
 
@@ -622,16 +636,15 @@ class ContinuousEngine:
         so later growth/free operate on the moved ids."""
         if not self.allocator.fragmented:
             return tables
-        t0 = self.tracer.now()
         remap = self.allocator.defrag()
         if remap:
-            self.pages, tables = kv_pool.apply_defrag(
-                self.pages, tables, remap)
-            for sr in sched.running.values():
-                sr.blocks = [remap.get(b, b) for b in sr.blocks]
+            with self.telemetry.span("defrag", cat="pool", step=now,
+                                     moved=len(remap)):
+                self.pages, tables = kv_pool.apply_defrag(
+                    self.pages, tables, remap)
+                for sr in sched.running.values():
+                    sr.blocks = [remap.get(b, b) for b in sr.blocks]
             self.metrics.counter("serve_defrags_total").inc()
-            self.tracer.span("defrag", t0, self.tracer.now(), cat="pool",
-                             args={"step": now, "moved": len(remap)})
         return tables
 
     def run(self, requests: Sequence[Request], *, key=None,
@@ -703,9 +716,15 @@ class ContinuousEngine:
         """Run the serve loop over a (fresh or restored) run state with the
         end-of-run cleanup both paths share."""
         self._run_state = st
+        loop = self._serve_loop(st, faults)
         try:
-            yield from self._serve_loop(st, faults)
+            for ev in loop:
+                # No span stays open while the consumer holds an event:
+                # the open ones close here and reopen as the loop resumes.
+                with self.telemetry.suspended():
+                    yield ev
         finally:
+            loop.close()
             # The generator may be abandoned mid-run (client drops the
             # stream) or killed by a CrashPoint: release every in-flight
             # request's blocks — running AND preempted-but-requeued —
@@ -714,6 +733,7 @@ class ContinuousEngine:
             # (Crash recovery reads the snapshot FILE, never this
             # in-memory state.)
             self._run_state = None
+            self._round_args = {}
             self._at_boundary = False
             self._drain_req = None
             self.allocator.unhide_all()
@@ -747,13 +767,12 @@ class ContinuousEngine:
     def _write_snapshot(self, st: _RunState, path: str | None = None) -> str:
         if path is None:
             path = os.path.join(self.snapshot_dir, "serve_snap.npz")
-        t0 = self.tracer.now()
-        path = snapshot_lib.save_snapshot(path, engine=self, state=st)
+        with self.telemetry.span("snapshot", cat="durability", step=st.now,
+                                 round=st.n_loops) as sp:
+            path = snapshot_lib.save_snapshot(path, engine=self, state=st)
+            sp.set(path=str(path))
         self.last_snapshot_path = path
         self.metrics.counter("serve_snapshots_total").inc()
-        self.tracer.span("snapshot", t0, self.tracer.now(), cat="durability",
-                         args={"step": st.now, "round": st.n_loops,
-                               "path": str(path)})
         return path
 
     def restore(self, path: str) -> "ContinuousEngine":
@@ -965,18 +984,17 @@ class ContinuousEngine:
             # growth-preallocated tail blocks past ctx hold no live state.
             ctx = int(st.lens[row])
             nb = kv_pool.blocks_for(max(ctx, 1), self.block_size)
-            t0 = self.tracer.now()
-            entry = kv_pool.SpillEntry(
-                kv=kv_pool.extract_blocks(self.pages, victim.blocks[:nb]),
-                n_blocks=nb, ctx_len=ctx, n_out=victim.n_out,
-                pending_tok=int(st.tok[row]))
-            self.spill.put(victim.rid, entry)
+            with self.telemetry.span("spill", cat="durability", step=now,
+                                     rid=victim.rid, blocks=nb) as sp:
+                entry = kv_pool.SpillEntry(
+                    kv=kv_pool.extract_blocks(self.pages,
+                                              victim.blocks[:nb]),
+                    n_blocks=nb, ctx_len=ctx, n_out=victim.n_out,
+                    pending_tok=int(st.tok[row]))
+                self.spill.put(victim.rid, entry)
+                sp.set(bytes=entry.nbytes)
             self.metrics.counter("serve_spills_total").inc()
             self.metrics.counter("serve_spill_bytes_total").inc(entry.nbytes)
-            self.tracer.span(
-                "spill", t0, self.tracer.now(), cat="durability",
-                args={"step": now, "rid": victim.rid, "blocks": nb,
-                      "bytes": entry.nbytes})
             victim.resume_prompt = None
             requeued, evicted = sched.preempt(victim, now, spill_blocks=nb)
         else:
@@ -1049,16 +1067,14 @@ class ContinuousEngine:
                         f"victim (rid={sr.rid}, block={src})")
                 yield from self._preempt_one(st, victim, now)
             dst = got[0]
-            tc = self.tracer.now()
-            self.pages = self._dispatch(
-                kv_pool.copy_block, self.pages, src, dst, name="cow_copy")
-            sr.blocks[i] = dst
-            tables[sr.row, i] = dst
-            self.allocator.free([src])
+            with self.telemetry.span("cow_copy", cat="pool", step=now,
+                                     rid=sr.rid, src=src, dst=dst):
+                self.pages = self._launch(kv_pool.copy_block, self.pages,
+                                          src, dst)
+                sr.blocks[i] = dst
+                tables[sr.row, i] = dst
+                self.allocator.free([src])
             self.metrics.counter("serve_cow_copies_total").inc()
-            self.tracer.span(
-                "cow_copy", tc, self.tracer.now(), cat="pool",
-                args={"step": now, "rid": sr.rid, "src": src, "dst": dst})
 
     # ------------------------------------------------------------ main loop
 
@@ -1085,605 +1101,617 @@ class ContinuousEngine:
         chunk = self.prefill_chunk
         mb = tok.shape[0]
         eligible_wall: dict[int, float] = {}
+        tel = self.telemetry
+        segments = self.metrics.counter("serve_segments_total")
         while sched.has_work:
             n_loops += 1
             t_round = time.perf_counter()
             poison_rids: set[int] = set()
 
-            # ---- segment boundary: every device result is harvested and
-            # host state is self-consistent — the ONLY place a snapshot is
-            # sound.  Sync the run state, then (a) checkpoint on the
-            # periodic cadence, (b) finish an elapsed drain.
-            st.tok, st.n_out, st.lens, st.done = tok, n_out, lens, done
-            st.tables = tables
-            st.now, st.n_loops = now, n_loops
-            self._at_boundary = True
-            if self._drain_req is not None and st.drain_at is None:
-                st.drain_at = now + self._drain_req[0]
-                st.drain_path = self._drain_req[1]
-                self._drain_req = None
-                self.tracer.instant(
-                    "drain_start", cat="durability",
-                    args={"step": now, "deadline": st.drain_at})
-            if st.drain_at is not None and (now >= st.drain_at
-                                            or not sched.running):
-                # Deadline hit or the batch quiesced: spill the stragglers
-                # (page_out — their KV rides the snapshot's spill section;
-                # other modes checkpoint them running/queued as-is), write
-                # the final snapshot, and end the run.
-                if self.preemption == "page_out":
-                    while sched.running:
-                        victim = sched.pick_victim()
-                        yield from self._preempt_one(st, victim, now)
-                path = self._write_snapshot(st, path=st.drain_path)
+            # Round-phase spans (schedule, inputs, the dispatch, harvest,
+            # emit) are profiler-only and carry the index of the segment
+            # this round dispatches and the sim clock.
+            self._round_args = rnd = {"round": segments.value + 1,
+                                      "step": now}
+            with tel.span("schedule", chrome=False, **rnd):
+                # ---- segment boundary: every device result is harvested
+                # and host state is self-consistent — the ONLY place a
+                # snapshot is sound.  Sync the run state, then (a)
+                # checkpoint on the periodic cadence, (b) finish an
+                # elapsed drain.
+                st.tok, st.n_out, st.lens, st.done = tok, n_out, lens, done
+                st.tables = tables
+                st.now, st.n_loops = now, n_loops
+                self._at_boundary = True
+                if self._drain_req is not None and st.drain_at is None:
+                    st.drain_at = now + self._drain_req[0]
+                    st.drain_path = self._drain_req[1]
+                    self._drain_req = None
+                    self.tracer.instant(
+                        "drain_start", cat="durability",
+                        args={"step": now, "deadline": st.drain_at})
+                if st.drain_at is not None and (now >= st.drain_at
+                                                or not sched.running):
+                    # Deadline hit or the batch quiesced: spill the
+                    # stragglers (page_out — their KV rides the snapshot's
+                    # spill section; other modes checkpoint them
+                    # running/queued as-is), write the final snapshot, and
+                    # end the run.
+                    if self.preemption == "page_out":
+                        while sched.running:
+                            victim = sched.pick_victim()
+                            yield from self._preempt_one(st, victim, now)
+                    path = self._write_snapshot(st, path=st.drain_path)
+                    self._at_boundary = False
+                    yield {"event": "drain", "step": now, "path": path,
+                           "running": len(sched.running),
+                           "spilled": len(self.spill),
+                           "queued": sched.queue_len}
+                    return
+                if (self.snapshot_interval
+                        and (n_loops - 1) % self.snapshot_interval == 0):
+                    self._write_snapshot(st)
                 self._at_boundary = False
-                yield {"event": "drain", "step": now, "path": path,
-                       "running": len(sched.running),
-                       "spilled": len(self.spill),
-                       "queued": sched.queue_len}
-                return
-            if (self.snapshot_interval
-                    and (n_loops - 1) % self.snapshot_interval == 0):
-                self._write_snapshot(st)
-            self._at_boundary = False
 
-            # ---- fault hook: chaos actions ride the real code paths ----
-            if faults is not None:
-                acts = faults.on_round(
-                    n_loops - 1, now,
-                    [sr.rid for sr in sched.running.values()],
-                    [r.rid for r in sched.arrived]
-                    + [s.rid for s in sched.preempted])
-                # Every injected action lands in the trace as a named
-                # instant, so a chaos run is visually replayable: the
-                # preemption storm that follows a fault:hide is right
-                # there on the timeline.
-                for ev_name, ev_args in faults_lib.describe(acts):
-                    self.tracer.instant(ev_name, cat="fault",
-                                        args={"step": now, **ev_args})
-                if acts.get("crash"):
-                    # Simulated hard death: no retires, no finish events —
-                    # recovery must come from the last snapshot file.
-                    raise faults_lib.CrashPoint(n_loops - 1, now)
-                if acts.get("unhide"):
-                    self.allocator.unhide_all()
-                if acts.get("hide"):
-                    self.allocator.hide_blocks(int(acts["hide"]))
-                if acts.get("flush"):
-                    # Drop every cached-free prefix entry: cache loss is
-                    # always correctness-neutral (future admissions just
-                    # miss), which is exactly what chaos should verify.
-                    self.allocator.drop_cached()
-                for rid in acts.get("cancel", ()):
-                    self._cancel_req.add(rid)
-                poison_rids = set(acts.get("poison", ()))
-                n_force = int(acts.get("preempt", 0))
-                if n_force and sched.preemptive:
-                    for _ in range(n_force):
-                        victim = sched.pick_victim()
-                        if victim is None:
-                            break
-                        yield from self._preempt_one(st, victim, now)
+                # ---- fault hook: chaos actions ride the real code paths ----
+                if faults is not None:
+                    acts = faults.on_round(
+                        n_loops - 1, now,
+                        [sr.rid for sr in sched.running.values()],
+                        [r.rid for r in sched.arrived]
+                        + [s.rid for s in sched.preempted])
+                    # Every injected action lands in the trace as a named
+                    # instant, so a chaos run is visually replayable: the
+                    # preemption storm that follows a fault:hide is right
+                    # there on the timeline.
+                    for ev_name, ev_args in faults_lib.describe(acts):
+                        self.tracer.instant(ev_name, cat="fault",
+                                            args={"step": now, **ev_args})
+                    if acts.get("crash"):
+                        # Simulated hard death: no retires, no finish
+                        # events — recovery must come from the last
+                        # snapshot file.
+                        raise faults_lib.CrashPoint(n_loops - 1, now)
+                    if acts.get("unhide"):
+                        self.allocator.unhide_all()
+                    if acts.get("hide"):
+                        self.allocator.hide_blocks(int(acts["hide"]))
+                    if acts.get("flush"):
+                        # Drop every cached-free prefix entry: cache loss is
+                        # always correctness-neutral (future admissions just
+                        # miss), which is exactly what chaos should verify.
+                        self.allocator.drop_cached()
+                    for rid in acts.get("cancel", ()):
+                        self._cancel_req.add(rid)
+                    poison_rids = set(acts.get("poison", ()))
+                    n_force = int(acts.get("preempt", 0))
+                    if n_force and sched.preemptive:
+                        for _ in range(n_force):
+                            victim = sched.pick_victim()
+                            if victim is None:
+                                break
+                            yield from self._preempt_one(st, victim, now)
 
-            # ---- arrivals, overload shedding, cancels, deadlines -------
-            if st.drain_at is None:
-                for req in sched.poll_arrivals(now):
-                    self.metrics.counter("serve_sheds_total").inc()
-                    yield self._retire_unadmitted(req, RequestStatus.SHED,
-                                                  now)
-            if self._cancel_req:
-                cancels = self.metrics.counter("serve_cancels_total")
-                for rid in sorted(self._cancel_req):
-                    sr = next((s for s in sched.running.values()
-                               if s.rid == rid), None)
-                    if sr is not None:
-                        cancels.inc()
+                # ---- arrivals, overload shedding, cancels, deadlines -------
+                if st.drain_at is None:
+                    for req in sched.poll_arrivals(now):
+                        self.metrics.counter("serve_sheds_total").inc()
+                        yield self._retire_unadmitted(req, RequestStatus.SHED,
+                                                      now)
+                if self._cancel_req:
+                    cancels = self.metrics.counter("serve_cancels_total")
+                    for rid in sorted(self._cancel_req):
+                        sr = next((s for s in sched.running.values()
+                                   if s.rid == rid), None)
+                        if sr is not None:
+                            cancels.inc()
+                            yield self._retire_record(
+                                st, sr, RequestStatus.CANCELLED, now)
+                            continue
+                        obj = sched.remove_queued(rid)
+                        if isinstance(obj, Request):
+                            cancels.inc()
+                            yield self._retire_unadmitted(
+                                obj, RequestStatus.CANCELLED, now)
+                        elif obj is not None:      # preempted, holds progress
+                            cancels.inc()
+                            yield self._retire_record(
+                                st, obj, RequestStatus.CANCELLED, now)
+                    self._cancel_req.clear()
+                for sr in list(sched.running.values()) + list(sched.preempted):
+                    dl = sr.req.deadline_steps
+                    if dl is not None and now - sr.req.arrival_step >= dl:
+                        self.metrics.counter("serve_timeouts_total").inc()
                         yield self._retire_record(
-                            st, sr, RequestStatus.CANCELLED, now)
-                        continue
-                    obj = sched.remove_queued(rid)
-                    if isinstance(obj, Request):
-                        cancels.inc()
-                        yield self._retire_unadmitted(
-                            obj, RequestStatus.CANCELLED, now)
-                    elif obj is not None:      # preempted, holds progress
-                        cancels.inc()
-                        yield self._retire_record(
-                            st, obj, RequestStatus.CANCELLED, now)
-                self._cancel_req.clear()
-            for sr in list(sched.running.values()) + list(sched.preempted):
-                dl = sr.req.deadline_steps
-                if dl is not None and now - sr.req.arrival_step >= dl:
+                            st, sr, RequestStatus.TIMEOUT, now)
+                for req in [r for r in sched.arrived
+                            if r.deadline_steps is not None
+                            and now - r.arrival_step >= r.deadline_steps]:
+                    sched.arrived.remove(req)
                     self.metrics.counter("serve_timeouts_total").inc()
-                    yield self._retire_record(
-                        st, sr, RequestStatus.TIMEOUT, now)
-            for req in [r for r in sched.arrived
-                        if r.deadline_steps is not None
-                        and now - r.arrival_step >= r.deadline_steps]:
-                sched.arrived.remove(req)
-                self.metrics.counter("serve_timeouts_total").inc()
-                yield self._retire_unadmitted(req, RequestStatus.TIMEOUT,
-                                              now)
+                    yield self._retire_unadmitted(req, RequestStatus.TIMEOUT,
+                                                  now)
 
-            # TTFT clock: a request becomes eligible the first round the
-            # sim reaches its arrival; wall TTFT is eligible -> first
-            # sampled token harvested (so queueing behind a busy pool AND
-            # head-of-line prefill stalls both count).
-            for r in sched.arrived:
-                if r.rid not in eligible_wall:
-                    eligible_wall[r.rid] = t_round
-                    self.tracer.request_point(r.rid, "arrive", step=now)
-            # Defrag policy: a fixed interval when configured (tests /
-            # worst-case bounding), else adaptively whenever the live span's
-            # hole fraction crosses the threshold — keeps block tables
-            # contiguous for the fused kernel's sequential page walks
-            # without paying a page permutation on every round.  The
-            # absolute hole-count floor stops a near-empty pool (one live
-            # block at slot 2 -> ratio 0.5) from buying a full-pool page
-            # permutation to relocate a couple of blocks.
-            if self.defrag_interval:
-                if n_loops % self.defrag_interval == 0:
-                    tables = st.tables = self._maybe_defrag(sched, tables,
-                                                            now)
-            elif (self.defrag_threshold is not None
-                  and self.allocator.hole_blocks >= self.defrag_min_holes
-                  and self.allocator.fragmentation()
-                  >= self.defrag_threshold):
-                tables = st.tables = self._maybe_defrag(sched, tables, now)
+                # TTFT clock: a request becomes eligible the first round the
+                # sim reaches its arrival; wall TTFT is eligible -> first
+                # sampled token harvested (so queueing behind a busy pool AND
+                # head-of-line prefill stalls both count).
+                for r in sched.arrived:
+                    if r.rid not in eligible_wall:
+                        eligible_wall[r.rid] = t_round
+                        self.tracer.request_point(r.rid, "arrive", step=now)
+                # Defrag policy: a fixed interval when configured (tests /
+                # worst-case bounding), else adaptively whenever the live
+                # span's hole fraction crosses the threshold — keeps block
+                # tables contiguous for the fused kernel's sequential page
+                # walks without paying a page permutation on every round.
+                # The absolute hole-count floor stops a near-empty pool (one
+                # live block at slot 2 -> ratio 0.5) from buying a full-pool
+                # page permutation to relocate a couple of blocks.
+                if self.defrag_interval:
+                    if n_loops % self.defrag_interval == 0:
+                        tables = st.tables = self._maybe_defrag(sched, tables,
+                                                                now)
+                elif (self.defrag_threshold is not None
+                      and self.allocator.hole_blocks >= self.defrag_min_holes
+                      and self.allocator.fragmentation()
+                      >= self.defrag_threshold):
+                    tables = st.tables = self._maybe_defrag(sched, tables, now)
 
-            # ---- admission (fresh arrivals, recompute re-admits, AND
-            # page-out restores); frozen while draining ----
-            pending_tok0: list[tuple[ScheduledRequest, Any]] = []
-            pf_wall = 0.0
-            admits = [] if st.drain_at is not None else \
-                sched.admit_ready(now)
-            for sr in admits:
-                row, req = sr.row, sr.req
-                rids[row] = req.rid
-                max_new[row] = req.max_new
-                stops[row] = -1
-                stops[row, :len(req.stop_tokens)] = req.stop_tokens
-                tables[row] = kv_pool.NULL_BLOCK
-                tables[row, :len(sr.blocks)] = sr.blocks
-                streams.setdefault(req.rid, ([], []))
-                had_cow = sr.cow_src >= 0
-                if had_cow:
-                    # Exact-hit copy-on-write: the scheduler mapped a fresh
-                    # dst block into the shared tail slot and decref'd the
-                    # src; copy the cached page NOW — dispatch order puts
-                    # this device copy ahead of any later prefill that
-                    # could recycle the src page.
-                    dst = sr.blocks[sr.pf_start // self.block_size]
-                    tc = self.tracer.now()
-                    self.pages = self._dispatch(
-                        kv_pool.copy_block, self.pages, sr.cow_src, dst,
-                        name="cow_copy")
-                    self.metrics.counter("serve_cow_copies_total").inc()
-                    self.tracer.span(
-                        "cow_copy", tc, self.tracer.now(), cat="pool",
-                        args={"step": now, "rid": req.rid,
-                              "src": sr.cow_src, "dst": dst})
-                    sr.cow_src = -1
-                if self.prefix_cache and not sr.spilled:
-                    if sr.shared_tokens > 0:
-                        self.metrics.counter(
-                            "serve_prefix_hits_total").inc()
-                        self.metrics.counter(
-                            "serve_prefix_hit_tokens_total").inc(
-                                sr.pf_start)
-                        self.tracer.request_point(
-                            req.rid, "prefix_hit", step=now,
-                            shared_tokens=sr.shared_tokens,
-                            suffix_start=sr.pf_start)
-                    else:
-                        self.metrics.counter(
-                            "serve_prefix_misses_total").inc()
-                if sr.spilled:
-                    # Page-out restore: scatter the spilled KV bytes into
-                    # the freshly allocated blocks, restore the host
-                    # cursors (incl. the pending sampled-but-unemitted
-                    # token), and rejoin decode directly — no prefill, no
-                    # recompute, bit-identical by construction.
-                    entry = self.spill.pop(req.rid)
-                    t0r = self.tracer.now()
-                    self.pages = kv_pool.insert_blocks(
-                        self.pages, entry.kv, sr.blocks)
-                    sr.spilled = False
-                    sr.spill_blocks = 0
-                    sr.state = State.DECODE
-                    sr.ctx_len = entry.ctx_len
-                    sr.n_out = entry.n_out
-                    sr.pf_written = 0
-                    n_out[row] = entry.n_out
-                    lens[row] = entry.ctx_len
-                    done[row] = False
-                    tok[row] = entry.pending_tok
-                    self.metrics.counter("serve_restores_total").inc()
-                    self.tracer.span(
-                        "spill_restore", t0r, self.tracer.now(),
-                        cat="durability",
-                        args={"step": now, "rid": req.rid,
-                              "blocks": entry.n_blocks,
-                              "bytes": entry.nbytes})
-                    self.tracer.request_point(req.rid, "restore", step=now,
-                                              row=row, n_out=sr.n_out)
-                    # The restored bytes are the original prefill's bytes:
-                    # re-index the prompt blocks for future sharers.
-                    self._register_prefix(sr, entry.ctx_len)
-                    yield {"event": "admit", "rid": req.rid, "step": now,
-                           "recompute": False, "restored": True}
-                    continue
-                n_out[row] = sr.n_out       # >0 on a recompute re-admit
-                if sr.n_preempt > 0:
-                    self.metrics.counter("serve_recomputes_total").inc()
-                else:
-                    self.metrics.histogram(
-                        "serve_queue_delay_steps").observe(
-                            now - req.arrival_step)
-                self.tracer.request_point(
-                    req.rid, "resume" if sr.n_preempt > 0 else "admit",
-                    step=now, row=row, blocks=len(sr.blocks))
-                if chunked:
-                    # The (possibly resumed) prompt streams into the pool
-                    # chunk by chunk inside the mixed segments; the row
-                    # idles in the decode loop (done) until its final
-                    # chunk samples the pending token.  Admission itself
-                    # dispatches nothing.  A prefix-cache hit seeds the
-                    # chunk cursor past the shared blocks (block-aligned),
-                    # so chunking starts at the unique suffix.
-                    sr.pf_written = sr.pf_start
-                    sr.ctx_len = sr.pf_start
-                    sr.cow_skip = had_cow
-                    lens[row] = 0
-                    done[row] = True
-                    tok[row] = 0
-                else:
-                    lens[row] = sr.cur_prompt_len
-                    done[row] = False
-                    t0 = time.perf_counter()
-                    ta = self.tracer.now()
-                    pending_tok0.append(
-                        (sr, self._admit(sr, plan, greedy, rng, temp,
-                                         skip_write=had_cow)))
-                    pf_wall += time.perf_counter() - t0
-                    self.tracer.span(
-                        "admit_prefill", ta, self.tracer.now(),
-                        cat="prefill", args={"step": now, "rid": req.rid})
-                    self._register_prefix(sr, sr.cur_prompt_len)
-                yield {"event": "admit", "rid": req.rid, "step": now,
-                       "recompute": sr.n_preempt > 0}
-            if pending_tok0:
-                # ONE device->host transfer for the whole admission round:
-                # the per-request prefill dispatches pipeline on device and
-                # the round joins once, instead of each admission blocking
-                # on its own int(tok0[0]).
-                t0 = time.perf_counter()
-                ta = self.tracer.now()
-                vals = jax.device_get([t for _, t in pending_tok0])
-                self.metrics.counter("serve_host_syncs_total").inc()
-                for (sr, _), v in zip(pending_tok0, vals):
-                    sr._tok0 = int(v[0])
-                    tok[sr.row] = sr._tok0
-                # Dispatch + join time only: the run_stream consumer's
-                # per-event work between admissions is not prefill cost.
-                self.metrics.counter("serve_prefill_seconds_total").inc(
-                    pf_wall + (time.perf_counter() - t0))
-                self.tracer.span(
-                    "admit_join", ta, self.tracer.now(), cat="prefill",
-                    args={"step": now, "n_requests": len(pending_tok0)})
-            self.metrics.gauge("serve_max_concurrency").set_max(
-                len(sched.running))
-            # Pool / batch health sampled once per round: gauges carry the
-            # latest value, bounded rings keep the raw per-round series,
-            # and 'C' trace events render stacked charts in perfetto.
-            stats = self.allocator.stats()
-            self.metrics.gauge("serve_pool_occupancy").set(
-                stats["occupancy"])
-            self.metrics.gauge("serve_pool_fragmentation").set(
-                stats["fragmentation"])
-            self.metrics.gauge("serve_pool_shared_blocks").set(
-                stats["shared"])
-            self.metrics.gauge("serve_pool_owned_blocks").set(
-                stats["owned"])
-            self.metrics.gauge("serve_pool_cached_blocks").set(
-                stats["cached"])
-            self.metrics.gauge("serve_running").set(len(sched.running))
-            if self.telemetry.enabled:
-                self.telemetry.occupancy_trace.append(
-                    (now, stats["occupancy"]))
-                self.telemetry.fragmentation_trace.append(
-                    (now, stats["fragmentation"]))
-                ts_round = self.tracer.now()
-                self.tracer.counter(
-                    "pool blocks", {"live": stats["live"],
-                                    "free": stats["free"],
-                                    "hidden": stats["hidden"],
-                                    "shared": stats["shared"],
-                                    "cached": stats["cached"]},
-                    ts=ts_round)
-                self.tracer.counter(
-                    "requests", {"running": len(sched.running),
-                                 "queued": sched.queue_len}, ts=ts_round)
-
-            if not sched.running:
-                if not sched.has_work:
-                    break                   # everything retired this round
-                nxt = sched.next_arrival()
-                if nxt is not None and nxt > now:
-                    now = nxt               # idle pool: jump to next arrival
-                    n_stalled = 0
-                    continue
-                # Admission blocked with nothing running (fault-hidden
-                # blocks, pathological max_queue): tick the clock and let
-                # the fault schedule advance; a bounded stall counter
-                # turns a genuine livelock into a loud failure.
-                now += 1
-                n_stalled += 1
-                if n_stalled > 10_000:
-                    raise RuntimeError(
-                        "scheduler stalled: nothing running and the "
-                        "admission head cannot be admitted "
-                        f"(free={self.allocator.free_blocks}, "
-                        f"hidden={self.allocator.hidden_blocks})")
-                continue
-            n_stalled = 0
-
-            # ---- growth (oldest-first; may preempt newest-admitted) ----
-            # Grow block tables to cover this segment's worst-case writes.
-            # Mid-prefill rows need no growth — their prompt blocks were
-            # allocated at admission and chunk-page writes past them land
-            # on null-table entries; a row whose FINAL chunk lands this
-            # segment starts decoding inside it, so it grows like a decode
-            # row.  Oldest-admitted rows grow first: a growth failure
-            # preempts the NEWEST victim, so the head of the FCFS line is
-            # never starved by a younger request's growth.
-            w_need = 1
-            for sr in sorted(sched.running.values(),
-                             key=lambda s: s.admit_seq):
-                if sched.running.get(sr.row) is not sr:
-                    continue               # preempted earlier this round
-                target = None
-                if chunked and sr.state is State.PREFILL:
-                    cnt = min(chunk, sr.cur_prompt_len - sr.pf_written)
-                    fin = sr.pf_written + cnt >= sr.cur_prompt_len
-                    span = sr.pf_written + chunk
-                    if fin:
-                        span = max(span,
-                                   sr.cur_prompt_len + self.segment_len)
-                        target = sr.cur_prompt_len + self.segment_len
-                else:
-                    span = int(lens[sr.row]) + self.segment_len
-                    target = sr.ctx_len + self.segment_len
-                if target is not None:
-                    new_blocks = yield from self._grow(st, sr, target, now)
-                    if new_blocks is None:
-                        continue           # self-preempted (fault pressure)
-                    if new_blocks:
-                        n_have = len(sr.blocks)
-                        tables[sr.row,
-                               n_have - len(new_blocks):n_have] = \
-                            new_blocks
-                if self.prefix_cache:
-                    ws = (sr.pf_written
-                          if chunked and sr.state is State.PREFILL
-                          else int(lens[sr.row]))
-                    yield from self._cow_writes(st, sr, ws, span, now,
-                                                tables)
-                    if sched.running.get(sr.row) is not sr:
-                        continue           # self-preempted under pressure
-                w_need = max(w_need,
-                             kv_pool.blocks_for(span, self.block_size))
-
-            if not sched.running:
-                continue                   # the whole batch got preempted
-
-            # The prefill-chunk work list (rows still streaming their
-            # prompt), built AFTER growth so preemption victims drop out.
-            pf_rows: list[tuple[int, ScheduledRequest, int, bool]] = []
-            if chunked:
-                for row, sr in sched.running.items():
-                    if sr.state is State.PREFILL:
-                        cnt = min(chunk,
-                                  sr.cur_prompt_len - sr.pf_written)
-                        fin = sr.pf_written + cnt >= sr.cur_prompt_len
-                        pf_rows.append((row, sr, cnt, fin))
-
-            # Poison vector: fault-injected NaN logits for these rids'
-            # rows, applied inside the jitted step (traced arg — changing
-            # targets never recompiles).
-            poison_v = np.zeros(mb, bool)
-            for row, sr in sched.running.items():
-                if sr.rid in poison_rids:
-                    poison_v[row] = True
-
-            # Dispatch only the live-width prefix of the tables: every
-            # row's blocks (incl. this segment's growth and prefill-chunk
-            # span) sit in the first w_need columns, so the device never
-            # sees the pool-sized table tail.  The width is bucketed to a
-            # power of two, bounding recompiles at O(log
-            # max_blocks_per_req) while both the gather reference and the
-            # fused kernel scale with live tokens instead of kv_blocks.
-            w = min(tables.shape[1], autotune.next_pow2(w_need))
-            seg_tables = np.ascontiguousarray(tables[:, :w])
-
-            if pf_rows:
-                # Mixed batch, ONE dispatch: chunk-prefill prologue over a
-                # pow2-bucketed sub-batch of ONLY the prefilling rows +
-                # the decode segment for everyone else.  Padding slots
-                # point at a non-prefilling row (a masked no-op, see
-                # _mixed_segment_fn).
-                pb = min(mb, autotune.next_pow2(len(pf_rows)))
-                pf_set = {row for row, *_ in pf_rows}
-                pad_row = next((r for r in range(mb) if r not in pf_set),
-                               0)
-                pf_idx = np.full(pb, pad_row, np.int32)
-                pf_tok = np.zeros((pb, chunk), np.int32)
-                pf_pos = np.zeros(pb, np.int32)
-                pf_cnt = np.zeros(pb, np.int32)
-                pf_on = np.zeros(pb, bool)
-                pf_nw = np.zeros(pb, bool)
-                pf_fin = np.zeros(pb, bool)
-                pf_t0 = np.zeros(pb, np.int32)
-                for i, (row, sr, cnt, fin) in enumerate(pf_rows):
-                    start = sr.pf_written
-                    pf_idx[i] = row
-                    pf_tok[i, :cnt] = sr.cur_prompt[start:start + cnt]
-                    pf_pos[i] = start
-                    pf_cnt[i] = cnt
-                    pf_on[i] = True
-                    pf_nw[i] = sr.cow_skip  # CoW dst already byte-exact
-                    pf_fin[i] = fin
-                    pf_t0[i] = sr.n_out     # >0: recompute re-admission
-                # The prologue's tables at their own tight width: just the
-                # prefilling rows' chunk spans, pow2-bucketed.  First-chunk
-                # rounds (all pos 0 — every short prompt) additionally
-                # skip the past gather entirely (static has_past hint).
-                pf_w_need = kv_pool.blocks_for(
-                    int((pf_pos + pf_cnt).max()), self.block_size)
-                pf_w = min(tables.shape[1],
-                           autotune.next_pow2(max(pf_w_need, 1)))
-                pf_tables = np.ascontiguousarray(tables[pf_idx, :pf_w])
-                has_past = bool(pf_pos.max() > 0)
-                mixed_fn = self._mixed_segment_fn(
-                    plan, greedy, self.segment_len, stop_w, chunk, pb,
-                    has_past)
-                t_seg = self.tracer.now()
-                outs = self._dispatch(
-                    mixed_fn, self.params, self.pages, seg_tables, pf_idx,
-                    pf_tables, pf_tok, pf_pos, pf_cnt, pf_on, pf_nw,
-                    pf_fin, pf_t0, tok, n_out, lens, done, rids, max_new,
-                    stops, poison_v, rng, temp, pad, name="mixed_segment")
-                self.metrics.counter("serve_prefill_chunks_total").inc(
-                    len(pf_rows))
-            else:
-                t_seg = self.tracer.now()
-                outs = self._dispatch(
-                    seg_fn, self.params, self.pages, seg_tables, tok,
-                    n_out, lens, done, rids, max_new, stops, poison_v,
-                    rng, temp, pad, name="decode_segment")
-            (pages, tok_d, n_out_d, lens_d, done_d, failed_d, out_t,
-             out_lp, i_exec) = outs
-            self.pages = pages
-            self.metrics.counter("serve_segments_total").inc()
-            # ONE device->host transfer for the whole harvest (np.array
-            # copies: the row state is mutated on admit/finish and raw jax
-            # buffers are read-only); the pages stay device-resident.
-            tok, n_out_new, lens, done, failed, out_t, out_lp, i_exec = (
-                np.array(a) for a in jax.device_get(
-                    (tok_d, n_out_d, lens_d, done_d, failed_d, out_t,
-                     out_lp, i_exec)))
-            # The harvest rebinds the row arrays: re-point the run state at
-            # the fresh copies so retires below (and the next boundary's
-            # snapshot) mutate/see the live ones.
-            st.tok, st.n_out, st.lens, st.done = tok, n_out_new, lens, done
-            self.metrics.counter("serve_host_syncs_total").inc()
-            t_harvest = time.perf_counter()
-            # The segment span covers dispatch -> harvested (device work +
-            # the one blocking join), i.e. everything between two
-            # scheduler rounds that isn't host bookkeeping.
-            self.tracer.span(
-                "segment", t_seg, self.tracer.now(),
-                args={"step": now,
-                      "index": self.metrics.value("serve_segments_total"),
-                      "kind": "mixed" if pf_rows else "decode",
-                      "rows_live": len(sched.running),
-                      "rows_prefill": len(pf_rows),
-                      "steps": int(i_exec), "table_width": int(w),
-                      "occupancy": stats["occupancy"],
-                      "fragmentation": stats["fragmentation"]})
-            n_out = n_out_new          # sr.n_out still holds the pre-segment
-            #                            count until each row is harvested
-            for row, sr, cnt, fin in pf_rows:
-                sr.pf_written += cnt
-                sr.ctx_len = sr.pf_written
-                sr.cow_skip = False        # write-skip covers one chunk
-                self.tracer.request_point(
-                    sr.rid, "prefill_chunk", step=now, n_tok=cnt,
-                    written=sr.pf_written, final=fin)
-                if fin and not failed[row]:
-                    # Index the prompt blocks only once the whole prompt
-                    # landed cleanly (a poisoned/NaN final chunk must not
-                    # publish pages future sharers would read).
-                    self._register_prefix(sr, sr.pf_written)
-
-            for row, sr in list(sched.running.items()):
-                if chunked and sr.state is State.PREFILL \
-                        and sr.pf_written < sr.cur_prompt_len:
-                    continue               # mid-prefill: nothing to harvest
-                cnt = int(n_out_new[row]) - sr.n_out
-                if cnt > 0:
-                    if sr.n_out == 0:
-                        sr.first_token_step = now + 1
-                        ttft = (t_harvest
-                                - eligible_wall.get(sr.rid, t_harvest))
-                        if sr.rid not in self.telemetry.ttft_seconds:
-                            # First token ever for this rid: one histogram
-                            # sample + one timeline milestone per request
-                            # (an int8 full-restart recompute re-enters
-                            # n_out==0 and would otherwise double-count).
-                            self.metrics.histogram(
-                                "serve_ttft_seconds").observe(ttft)
-                            self.tracer.request_point(
-                                sr.rid, "first_token", step=now + 1,
-                                ttft_s=ttft)
-                        self.telemetry.ttft_seconds[sr.rid] = ttft
-                    if sr.state is State.PREFILL:
-                        sr.state = State.DECODE
-                    streams[sr.rid][0].extend(
-                        int(t) for t in out_t[row, :cnt])
-                    streams[sr.rid][1].extend(
-                        float(x) for x in out_lp[row, :cnt])
-                    yield {"event": "tokens", "rid": sr.rid,
-                           "step": now + cnt,
-                           "tokens": list(out_t[row, :cnt]),
-                           "logprobs": list(out_lp[row, :cnt])}
-                sr.n_out = int(n_out_new[row])
-                sr.ctx_len = int(lens[row])
-                if failed[row]:
-                    # Non-finite logits quarantined this row mid-segment:
-                    # its clean prefix was harvested above; the batch
-                    # peers never saw the NaN.
-                    self.metrics.counter("serve_failed_total").inc()
-                    yield self._retire_record(
-                        st, sr, RequestStatus.FAILED, now + cnt)
-                elif done[row]:
-                    toks, lps = streams.pop(sr.rid)
-                    # Stop wins ties (a stop token emitted ON the last
-                    # allowed step), matching Engine.generate's done flag.
-                    reason = ("stop" if toks and
-                              toks[-1] in sr.req.stop_tokens else "length")
-                    sched.finish(sr, now + cnt)
-                    # Hygiene: retired rows point at the null block with no
-                    # valid positions until the row is reused.
+                # ---- admission (fresh arrivals, recompute re-admits, AND
+                # page-out restores); frozen while draining ----
+                pending_tok0: list[tuple[ScheduledRequest, Any]] = []
+                pf_wall = 0.0
+                admits = [] if st.drain_at is not None else \
+                    sched.admit_ready(now)
+                for sr in admits:
+                    row, req = sr.row, sr.req
+                    rids[row] = req.rid
+                    max_new[row] = req.max_new
+                    stops[row] = -1
+                    stops[row, :len(req.stop_tokens)] = req.stop_tokens
                     tables[row] = kv_pool.NULL_BLOCK
-                    lens[row] = 0
-                    self.metrics.counter(
-                        "serve_requests_total",
-                        "Requests retired, by terminal status",
-                        labels={"status": RequestStatus.OK.value}).inc()
-                    self.metrics.histogram(
-                        "serve_request_latency_steps").observe(
-                            sr.finished_step - sr.req.arrival_step)
-                    self.tracer.request_retire(
-                        sr.rid, RequestStatus.OK.value,
-                        step=sr.finished_step, n_tokens=len(toks),
-                        finish_reason=reason)
-                    result = RequestResult(
-                        rid=sr.rid,
-                        tokens=np.asarray(toks, np.int32),
-                        logprobs=np.asarray(lps, np.float32),
-                        finish_reason=reason,
-                        arrival_step=sr.req.arrival_step,
-                        admitted_step=sr.admitted_step,
-                        first_token_step=sr.first_token_step,
-                        finished_step=sr.finished_step,
-                        ttft_seconds=self.last_run_ttft_seconds.get(
-                            sr.rid, float("nan")),
-                        status=RequestStatus.OK,
-                        n_preemptions=sr.n_preempt)
-                    yield {"event": "finish", "rid": sr.rid,
-                           "step": sr.finished_step, "result": result}
-            now += int(i_exec)
+                    tables[row, :len(sr.blocks)] = sr.blocks
+                    streams.setdefault(req.rid, ([], []))
+                    had_cow = sr.cow_src >= 0
+                    if had_cow:
+                        # Exact-hit copy-on-write: the scheduler mapped a fresh
+                        # dst block into the shared tail slot and decref'd the
+                        # src; copy the cached page NOW — dispatch order puts
+                        # this device copy ahead of any later prefill that
+                        # could recycle the src page.
+                        dst = sr.blocks[sr.pf_start // self.block_size]
+                        with self.telemetry.span(
+                                "cow_copy", cat="pool", step=now, rid=req.rid,
+                                src=sr.cow_src, dst=dst):
+                            self.pages = self._launch(
+                                kv_pool.copy_block, self.pages, sr.cow_src,
+                                dst)
+                        self.metrics.counter("serve_cow_copies_total").inc()
+                        sr.cow_src = -1
+                    if self.prefix_cache and not sr.spilled:
+                        if sr.shared_tokens > 0:
+                            self.metrics.counter(
+                                "serve_prefix_hits_total").inc()
+                            self.metrics.counter(
+                                "serve_prefix_hit_tokens_total").inc(
+                                    sr.pf_start)
+                            self.tracer.request_point(
+                                req.rid, "prefix_hit", step=now,
+                                shared_tokens=sr.shared_tokens,
+                                suffix_start=sr.pf_start)
+                        else:
+                            self.metrics.counter(
+                                "serve_prefix_misses_total").inc()
+                    if sr.spilled:
+                        # Page-out restore: scatter the spilled KV bytes into
+                        # the freshly allocated blocks, restore the host
+                        # cursors (incl. the pending sampled-but-unemitted
+                        # token), and rejoin decode directly — no prefill, no
+                        # recompute, bit-identical by construction.
+                        entry = self.spill.pop(req.rid)
+                        with self.telemetry.span(
+                                "spill_restore", cat="durability", step=now,
+                                rid=req.rid, blocks=entry.n_blocks,
+                                bytes=entry.nbytes):
+                            self.pages = kv_pool.insert_blocks(
+                                self.pages, entry.kv, sr.blocks)
+                        sr.spilled = False
+                        sr.spill_blocks = 0
+                        sr.state = State.DECODE
+                        sr.ctx_len = entry.ctx_len
+                        sr.n_out = entry.n_out
+                        sr.pf_written = 0
+                        n_out[row] = entry.n_out
+                        lens[row] = entry.ctx_len
+                        done[row] = False
+                        tok[row] = entry.pending_tok
+                        self.metrics.counter("serve_restores_total").inc()
+                        self.tracer.request_point(req.rid, "restore", step=now,
+                                                  row=row, n_out=sr.n_out)
+                        # The restored bytes are the original prefill's bytes:
+                        # re-index the prompt blocks for future sharers.
+                        self._register_prefix(sr, entry.ctx_len)
+                        yield {"event": "admit", "rid": req.rid, "step": now,
+                               "recompute": False, "restored": True}
+                        continue
+                    n_out[row] = sr.n_out       # >0 on a recompute re-admit
+                    if sr.n_preempt > 0:
+                        self.metrics.counter("serve_recomputes_total").inc()
+                    else:
+                        self.metrics.histogram(
+                            "serve_queue_delay_steps").observe(
+                                now - req.arrival_step)
+                    self.tracer.request_point(
+                        req.rid, "resume" if sr.n_preempt > 0 else "admit",
+                        step=now, row=row, blocks=len(sr.blocks))
+                    if chunked:
+                        # The (possibly resumed) prompt streams into the pool
+                        # chunk by chunk inside the mixed segments; the row
+                        # idles in the decode loop (done) until its final
+                        # chunk samples the pending token.  Admission itself
+                        # dispatches nothing.  A prefix-cache hit seeds the
+                        # chunk cursor past the shared blocks (block-aligned),
+                        # so chunking starts at the unique suffix.
+                        sr.pf_written = sr.pf_start
+                        sr.ctx_len = sr.pf_start
+                        sr.cow_skip = had_cow
+                        lens[row] = 0
+                        done[row] = True
+                        tok[row] = 0
+                    else:
+                        lens[row] = sr.cur_prompt_len
+                        done[row] = False
+                        t0 = time.perf_counter()
+                        with self.telemetry.span("admit_prefill",
+                                                 cat="prefill", step=now,
+                                                 rid=req.rid):
+                            pending_tok0.append(
+                                (sr, self._admit(sr, plan, greedy, rng, temp,
+                                                 skip_write=had_cow)))
+                        pf_wall += time.perf_counter() - t0
+                        self._register_prefix(sr, sr.cur_prompt_len)
+                    yield {"event": "admit", "rid": req.rid, "step": now,
+                           "recompute": sr.n_preempt > 0}
+                if pending_tok0:
+                    # ONE device->host transfer for the whole admission round:
+                    # the per-request prefill dispatches pipeline on device and
+                    # the round joins once, instead of each admission blocking
+                    # on its own int(tok0[0]).
+                    t0 = time.perf_counter()
+                    with self.telemetry.span("admit_join", cat="prefill",
+                                             step=now,
+                                             n_requests=len(pending_tok0)):
+                        vals = jax.device_get([t for _, t in pending_tok0])
+                    self.metrics.counter("serve_host_syncs_total").inc()
+                    for (sr, _), v in zip(pending_tok0, vals):
+                        sr._tok0 = int(v[0])
+                        tok[sr.row] = sr._tok0
+                    # Dispatch + join time only: the run_stream consumer's
+                    # per-event work between admissions is not prefill cost.
+                    self.metrics.counter("serve_prefill_seconds_total").inc(
+                        pf_wall + (time.perf_counter() - t0))
+                self.metrics.gauge("serve_max_concurrency").set_max(
+                    len(sched.running))
+                # Pool / batch health sampled once per round: gauges carry the
+                # latest value, bounded rings keep the raw per-round series,
+                # and 'C' trace events render stacked charts in perfetto.
+                stats = self.allocator.stats()
+                self.metrics.gauge("serve_pool_occupancy").set(
+                    stats["occupancy"])
+                self.metrics.gauge("serve_pool_fragmentation").set(
+                    stats["fragmentation"])
+                self.metrics.gauge("serve_pool_shared_blocks").set(
+                    stats["shared"])
+                self.metrics.gauge("serve_pool_owned_blocks").set(
+                    stats["owned"])
+                self.metrics.gauge("serve_pool_cached_blocks").set(
+                    stats["cached"])
+                self.metrics.gauge("serve_running").set(len(sched.running))
+                if self.telemetry.enabled:
+                    self.telemetry.occupancy_trace.append(
+                        (now, stats["occupancy"]))
+                    self.telemetry.fragmentation_trace.append(
+                        (now, stats["fragmentation"]))
+                    ts_round = self.tracer.now()
+                    self.tracer.counter(
+                        "pool blocks", {"live": stats["live"],
+                                        "free": stats["free"],
+                                        "hidden": stats["hidden"],
+                                        "shared": stats["shared"],
+                                        "cached": stats["cached"]},
+                        ts=ts_round)
+                    self.tracer.counter(
+                        "requests", {"running": len(sched.running),
+                                     "queued": sched.queue_len}, ts=ts_round)
+
+                if not sched.running:
+                    if not sched.has_work:
+                        break                   # everything retired this round
+                    nxt = sched.next_arrival()
+                    if nxt is not None and nxt > now:
+                        now = nxt           # idle pool: jump to next arrival
+                        n_stalled = 0
+                        continue
+                    # Admission blocked with nothing running (fault-hidden
+                    # blocks, pathological max_queue): tick the clock and let
+                    # the fault schedule advance; a bounded stall counter
+                    # turns a genuine livelock into a loud failure.
+                    now += 1
+                    n_stalled += 1
+                    if n_stalled > 10_000:
+                        raise RuntimeError(
+                            "scheduler stalled: nothing running and the "
+                            "admission head cannot be admitted "
+                            f"(free={self.allocator.free_blocks}, "
+                            f"hidden={self.allocator.hidden_blocks})")
+                    continue
+                n_stalled = 0
+
+                # ---- growth (oldest-first; may preempt newest-admitted) ----
+                # Grow block tables to cover this segment's worst-case writes.
+                # Mid-prefill rows need no growth — their prompt blocks were
+                # allocated at admission and chunk-page writes past them land
+                # on null-table entries; a row whose FINAL chunk lands this
+                # segment starts decoding inside it, so it grows like a decode
+                # row.  Oldest-admitted rows grow first: a growth failure
+                # preempts the NEWEST victim, so the head of the FCFS line is
+                # never starved by a younger request's growth.
+                w_need = 1
+                for sr in sorted(sched.running.values(),
+                                 key=lambda s: s.admit_seq):
+                    if sched.running.get(sr.row) is not sr:
+                        continue               # preempted earlier this round
+                    target = None
+                    if chunked and sr.state is State.PREFILL:
+                        cnt = min(chunk, sr.cur_prompt_len - sr.pf_written)
+                        fin = sr.pf_written + cnt >= sr.cur_prompt_len
+                        span = sr.pf_written + chunk
+                        if fin:
+                            span = max(span,
+                                       sr.cur_prompt_len + self.segment_len)
+                            target = sr.cur_prompt_len + self.segment_len
+                    else:
+                        span = int(lens[sr.row]) + self.segment_len
+                        target = sr.ctx_len + self.segment_len
+                    if target is not None:
+                        new_blocks = yield from self._grow(st, sr, target, now)
+                        if new_blocks is None:
+                            continue       # self-preempted (fault pressure)
+                        if new_blocks:
+                            n_have = len(sr.blocks)
+                            tables[sr.row,
+                                   n_have - len(new_blocks):n_have] = \
+                                new_blocks
+                    if self.prefix_cache:
+                        ws = (sr.pf_written
+                              if chunked and sr.state is State.PREFILL
+                              else int(lens[sr.row]))
+                        yield from self._cow_writes(st, sr, ws, span, now,
+                                                    tables)
+                        if sched.running.get(sr.row) is not sr:
+                            continue           # self-preempted under pressure
+                    w_need = max(w_need,
+                                 kv_pool.blocks_for(span, self.block_size))
+
+                if not sched.running:
+                    continue                   # the whole batch got preempted
+
+            with tel.span("inputs", chrome=False, **rnd):
+                # The prefill-chunk work list (rows still streaming their
+                # prompt), built AFTER growth so preemption victims drop out.
+                pf_rows: list[tuple[int, ScheduledRequest, int, bool]] = []
+                if chunked:
+                    for row, sr in sched.running.items():
+                        if sr.state is State.PREFILL:
+                            cnt = min(chunk,
+                                      sr.cur_prompt_len - sr.pf_written)
+                            fin = sr.pf_written + cnt >= sr.cur_prompt_len
+                            pf_rows.append((row, sr, cnt, fin))
+
+                # Poison vector: fault-injected NaN logits for these rids'
+                # rows, applied inside the jitted step (traced arg — changing
+                # targets never recompiles).
+                poison_v = np.zeros(mb, bool)
+                for row, sr in sched.running.items():
+                    if sr.rid in poison_rids:
+                        poison_v[row] = True
+
+                # Dispatch only the live-width prefix of the tables: every
+                # row's blocks (incl. this segment's growth and prefill-chunk
+                # span) sit in the first w_need columns, so the device never
+                # sees the pool-sized table tail.  The width is bucketed to a
+                # power of two, bounding recompiles at O(log
+                # max_blocks_per_req) while both the gather reference and the
+                # fused kernel scale with live tokens instead of kv_blocks.
+                w = min(tables.shape[1], autotune.next_pow2(w_need))
+                seg_tables = np.ascontiguousarray(tables[:, :w])
+
+                if pf_rows:
+                    # Mixed batch, ONE dispatch: chunk-prefill prologue over a
+                    # pow2-bucketed sub-batch of ONLY the prefilling rows +
+                    # the decode segment for everyone else.  Padding slots
+                    # point at a non-prefilling row (a masked no-op, see
+                    # _mixed_segment_fn).
+                    pb = min(mb, autotune.next_pow2(len(pf_rows)))
+                    pf_set = {row for row, *_ in pf_rows}
+                    pad_row = next((r for r in range(mb) if r not in pf_set),
+                                   0)
+                    pf_idx = np.full(pb, pad_row, np.int32)
+                    pf_tok = np.zeros((pb, chunk), np.int32)
+                    pf_pos = np.zeros(pb, np.int32)
+                    pf_cnt = np.zeros(pb, np.int32)
+                    pf_on = np.zeros(pb, bool)
+                    pf_nw = np.zeros(pb, bool)
+                    pf_fin = np.zeros(pb, bool)
+                    pf_t0 = np.zeros(pb, np.int32)
+                    for i, (row, sr, cnt, fin) in enumerate(pf_rows):
+                        start = sr.pf_written
+                        pf_idx[i] = row
+                        pf_tok[i, :cnt] = sr.cur_prompt[start:start + cnt]
+                        pf_pos[i] = start
+                        pf_cnt[i] = cnt
+                        pf_on[i] = True
+                        pf_nw[i] = sr.cow_skip  # CoW dst already byte-exact
+                        pf_fin[i] = fin
+                        pf_t0[i] = sr.n_out     # >0: recompute re-admission
+                    # The prologue's tables at their own tight width: just the
+                    # prefilling rows' chunk spans, pow2-bucketed.  First-chunk
+                    # rounds (all pos 0 — every short prompt) additionally
+                    # skip the past gather entirely (static has_past hint).
+                    pf_w_need = kv_pool.blocks_for(
+                        int((pf_pos + pf_cnt).max()), self.block_size)
+                    pf_w = min(tables.shape[1],
+                               autotune.next_pow2(max(pf_w_need, 1)))
+                    pf_tables = np.ascontiguousarray(tables[pf_idx, :pf_w])
+                    has_past = bool(pf_pos.max() > 0)
+                    mixed_fn = self._mixed_segment_fn(
+                        plan, greedy, self.segment_len, stop_w, chunk, pb,
+                        has_past)
+                    args = (self.params, self.pages, seg_tables, pf_idx,
+                            pf_tables, pf_tok, pf_pos, pf_cnt, pf_on, pf_nw,
+                            pf_fin, pf_t0, tok, n_out, lens, done, rids,
+                            max_new, stops, poison_v, rng, temp, pad)
+                    fn, kind = mixed_fn, "mixed"
+                else:
+                    args = (self.params, self.pages, seg_tables, tok, n_out,
+                            lens, done, rids, max_new, stops, poison_v, rng,
+                            temp, pad)
+                    fn, kind = seg_fn, "decode"
+
+            # The Chrome segment span covers dispatch -> harvested (device
+            # work + the one blocking join); a device profile has the
+            # dispatch and harvest spans instead.  Harvest runs from the
+            # dispatch's return and emit from harvest's end, so no host
+            # work of the round falls between two phase spans.
+            with tel.span("segment", profile=False, step=now,
+                          index=rnd["round"], kind=kind) as seg_span:
+                outs = self._dispatch(fn, *args, name=kind + "_segment")
+                with tel.span("harvest", chrome=False, **rnd):
+                    del args               # the old pool's last reference
+                    (pages, tok_d, n_out_d, lens_d, done_d, failed_d, out_t,
+                     out_lp, i_exec) = outs
+                    self.pages = pages
+                    segments.inc()
+                    if pf_rows:
+                        self.metrics.counter(
+                            "serve_prefill_chunks_total").inc(len(pf_rows))
+                    # ONE device->host transfer for the whole harvest
+                    # (np.array copies: the row state is mutated on
+                    # admit/finish and raw jax buffers are read-only); the
+                    # pages stay device-resident.
+                    (tok, n_out_new, lens, done, failed, out_t, out_lp,
+                     i_exec) = (np.array(a) for a in jax.device_get(
+                        (tok_d, n_out_d, lens_d, done_d, failed_d, out_t,
+                         out_lp, i_exec)))
+                    seg_span.set(rows_live=len(sched.running),
+                                 rows_prefill=len(pf_rows),
+                                 steps=int(i_exec), table_width=int(w),
+                                 occupancy=stats["occupancy"],
+                                 fragmentation=stats["fragmentation"])
+
+            with tel.span("emit", chrome=False, **rnd):
+                # The harvest rebinds the row arrays: re-point the run
+                # state at the fresh copies so retires below (and the next
+                # boundary's snapshot) mutate/see the live ones.
+                st.tok, st.n_out, st.lens, st.done = (tok, n_out_new, lens,
+                                                      done)
+                self.metrics.counter("serve_host_syncs_total").inc()
+                t_harvest = time.perf_counter()
+                # sr.n_out still holds the pre-segment count until each row
+                # is harvested.
+                n_out = n_out_new
+                for row, sr, cnt, fin in pf_rows:
+                    sr.pf_written += cnt
+                    sr.ctx_len = sr.pf_written
+                    sr.cow_skip = False        # write-skip covers one chunk
+                    self.tracer.request_point(
+                        sr.rid, "prefill_chunk", step=now, n_tok=cnt,
+                        written=sr.pf_written, final=fin)
+                    if fin and not failed[row]:
+                        # Index the prompt blocks only once the whole prompt
+                        # landed cleanly (a poisoned/NaN final chunk must not
+                        # publish pages future sharers would read).
+                        self._register_prefix(sr, sr.pf_written)
+
+                for row, sr in list(sched.running.items()):
+                    if chunked and sr.state is State.PREFILL \
+                            and sr.pf_written < sr.cur_prompt_len:
+                        continue           # mid-prefill: nothing to harvest
+                    cnt = int(n_out_new[row]) - sr.n_out
+                    if cnt > 0:
+                        if sr.n_out == 0:
+                            sr.first_token_step = now + 1
+                            ttft = (t_harvest
+                                    - eligible_wall.get(sr.rid, t_harvest))
+                            if sr.rid not in self.telemetry.ttft_seconds:
+                                # First token ever for this rid: one histogram
+                                # sample + one timeline milestone per request
+                                # (an int8 full-restart recompute re-enters
+                                # n_out==0 and would otherwise double-count).
+                                self.metrics.histogram(
+                                    "serve_ttft_seconds").observe(ttft)
+                                self.tracer.request_point(
+                                    sr.rid, "first_token", step=now + 1,
+                                    ttft_s=ttft)
+                            self.telemetry.ttft_seconds[sr.rid] = ttft
+                        if sr.state is State.PREFILL:
+                            sr.state = State.DECODE
+                        streams[sr.rid][0].extend(
+                            int(t) for t in out_t[row, :cnt])
+                        streams[sr.rid][1].extend(
+                            float(x) for x in out_lp[row, :cnt])
+                        yield {"event": "tokens", "rid": sr.rid,
+                               "step": now + cnt,
+                               "tokens": list(out_t[row, :cnt]),
+                               "logprobs": list(out_lp[row, :cnt])}
+                    sr.n_out = int(n_out_new[row])
+                    sr.ctx_len = int(lens[row])
+                    if failed[row]:
+                        # Non-finite logits quarantined this row mid-segment:
+                        # its clean prefix was harvested above; the batch
+                        # peers never saw the NaN.
+                        self.metrics.counter("serve_failed_total").inc()
+                        yield self._retire_record(
+                            st, sr, RequestStatus.FAILED, now + cnt)
+                    elif done[row]:
+                        toks, lps = streams.pop(sr.rid)
+                        # Stop wins ties (a stop token emitted ON the last
+                        # allowed step), matching Engine.generate's done flag.
+                        reason = ("stop" if toks and
+                                  toks[-1] in sr.req.stop_tokens else "length")
+                        sched.finish(sr, now + cnt)
+                        # Hygiene: retired rows point at the null block with no
+                        # valid positions until the row is reused.
+                        tables[row] = kv_pool.NULL_BLOCK
+                        lens[row] = 0
+                        self.metrics.counter(
+                            "serve_requests_total",
+                            "Requests retired, by terminal status",
+                            labels={"status": RequestStatus.OK.value}).inc()
+                        self.metrics.histogram(
+                            "serve_request_latency_steps").observe(
+                                sr.finished_step - sr.req.arrival_step)
+                        self.tracer.request_retire(
+                            sr.rid, RequestStatus.OK.value,
+                            step=sr.finished_step, n_tokens=len(toks),
+                            finish_reason=reason)
+                        result = RequestResult(
+                            rid=sr.rid,
+                            tokens=np.asarray(toks, np.int32),
+                            logprobs=np.asarray(lps, np.float32),
+                            finish_reason=reason,
+                            arrival_step=sr.req.arrival_step,
+                            admitted_step=sr.admitted_step,
+                            first_token_step=sr.first_token_step,
+                            finished_step=sr.finished_step,
+                            ttft_seconds=self.last_run_ttft_seconds.get(
+                                sr.rid, float("nan")),
+                            status=RequestStatus.OK,
+                            n_preemptions=sr.n_preempt)
+                        yield {"event": "finish", "rid": sr.rid,
+                               "step": sr.finished_step, "result": result}
+                now += int(i_exec)
 
     # ---------------------------------------------------------------- admit
 

@@ -38,16 +38,23 @@ through them — but turns every tracer call into an early-out, so the token
 stream is bit-identical either way (tested) and the serve loop pays only
 dict-lookup-free guard checks.
 
-Optionally (``profiler_annotations=True``) each jitted dispatch is wrapped
-in a ``jax.profiler.TraceAnnotation`` scope named after its engine span, so
-a device profile captured with ``jax.profiler.trace`` lines up 1:1 with the
-engine's own segment spans in perfetto.
+Every engine span goes through :meth:`Telemetry.span`, one context
+manager with two sinks: the tracer's Chrome ``X`` event (when tracing is
+on) and, with ``profiler_annotations=True``, a
+``jax.profiler.TraceAnnotation`` named ``serve/<name>`` whose keyword
+arguments become event stats, so a profile captured with
+``jax.profiler.trace`` shows the engine's spans on the same clock as the
+device ops.  A span may keep to one sink: the per-round phase and
+dispatch spans are profiler-only, the Chrome ``segment`` span is
+Chrome-only.  :meth:`Telemetry.suspended` closes every open profiler span
+while the engine's event stream is handed to its consumer and reopens it
+after, so no profiler span covers the consumer's own time.
 """
 from __future__ import annotations
 
 import bisect
 import collections
-import contextlib
+import functools
 import json
 import math
 import time
@@ -57,8 +64,8 @@ import numpy as np
 
 __all__ = [
     "percentile", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Tracer", "Telemetry", "SERVE_METRICS", "declare_serve_metrics",
-    "validate_chrome_trace",
+    "Tracer", "Telemetry", "Span", "NULL_SPAN", "SERVE_METRICS",
+    "declare_serve_metrics", "validate_chrome_trace",
 ]
 
 
@@ -601,9 +608,10 @@ class Telemetry:
     holds at most that many points (the registry gauges always carry the
     latest sample regardless).
 
-    ``profiler_annotations=True`` makes :meth:`annotate` yield a
-    ``jax.profiler.TraceAnnotation`` scope (otherwise a null context), so
-    engine dispatch spans show up named inside a captured device profile.
+    ``profiler_annotations=True`` adds the profiler sink to
+    :meth:`span`: each span also opens a ``jax.profiler.TraceAnnotation``
+    named ``serve/<name>``, so it shows up inside a captured device
+    profile.
     """
 
     def __init__(self, *, enabled: bool = True, trace_samples: int = 4096,
@@ -614,6 +622,7 @@ class Telemetry:
         self.profiler_annotations = profiler_annotations
         self.metrics = declare_serve_metrics(MetricsRegistry())
         self.tracer = Tracer(enabled=enabled, max_events=max_trace_events)
+        self._open: list[Span] = []         # open spans, outermost first
         self.reset_run()
 
     def reset_run(self) -> None:
@@ -622,6 +631,7 @@ class Telemetry:
         instruments, rewinds the tracer, and empties the raw rings."""
         self.metrics.reset_run()
         self.tracer.reset()
+        self._open.clear()
         self.ttft_seconds: dict[int, float] = {}
         self.occupancy_trace: collections.deque = collections.deque(
             maxlen=self.trace_samples)
@@ -634,17 +644,119 @@ class Telemetry:
         self.enabled = enabled
         self.tracer.enabled = enabled
 
-    def annotate(self, name: str):
-        """Context manager for a jitted dispatch: a named
-        ``jax.profiler.TraceAnnotation`` scope when profiler annotations
-        are on, else a free null context."""
-        if self.profiler_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                return TraceAnnotation(name)
-            except ImportError:        # profiler not available on backend
-                pass
-        return contextlib.nullcontext()
+    def span(self, name: str, *, cat: str = "serve", chrome: bool = True,
+             profile: bool = True, **args):
+        """Context manager timing the enclosed block on up to two sinks: a
+        Chrome ``X`` event ``name`` (category `cat`) when `chrome` is true
+        and the tracer is on, and a ``jax.profiler.TraceAnnotation(
+        "serve/" + name, **args)`` when `profile` is true and profiler
+        annotations are on.  ``with ... as sp`` gives a :class:`Span`;
+        ``sp.set(**kw)`` adds Chrome args before it closes.  With no sink
+        active it returns one shared null span and reads no clock."""
+        chrome = chrome and self.tracer.enabled
+        profile = profile and self.profiler_annotations
+        if chrome or profile:
+            return Span(self, name, cat, chrome, profile, args)
+        return NULL_SPAN
+
+    def suspended(self):
+        """Context manager that closes the profiler slice of every open
+        span for the duration of the block and reopens it (as a new slice)
+        after, unless it raised: the engine wraps each event it hands its
+        consumer in it, so no profiler span covers the consumer's time.
+        Chrome events are not cut: each span stays one event."""
+        return _Suspension(self._open) if self._open else NULL_SPAN
+
+
+class Span:
+    """An open :meth:`Telemetry.span`: one Chrome event from its open to
+    its close, and one profiler annotation per stretch that
+    :meth:`Telemetry.suspended` did not cut."""
+
+    __slots__ = ("_tel", "name", "cat", "args", "_chrome", "_profile",
+                 "_t0", "_ann")
+
+    def __init__(self, tel: Telemetry, name: str, cat: str, chrome: bool,
+                 profile: bool, args: dict):
+        self._tel, self.name, self.cat, self.args = tel, name, cat, args
+        self._chrome, self._profile = chrome, profile
+        self._t0 = self._ann = None
+
+    def set(self, **args) -> None:
+        """Add Chrome args (the profiler's are fixed when a slice opens)."""
+        self.args.update(args)
+
+    # The profiler slice opens first and closes last, so that it also
+    # covers the Chrome sink's bookkeeping: a device profile then finds
+    # the engine's own span over as much of its host time as it can.
+    def __enter__(self) -> "Span":
+        if self._profile:
+            self._tel._open.append(self)
+            self._open_slice()
+        if self._chrome:
+            self._t0 = self._tel.tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._t0 is not None:
+            tr = self._tel.tracer
+            tr.span(self.name, self._t0, tr.now(), cat=self.cat,
+                    args=dict(self.args))
+        if self._profile:
+            self._close_slice()
+            if self in self._tel._open:     # not after a reset_run
+                self._tel._open.remove(self)
+
+    def _open_slice(self) -> None:
+        self._ann = _trace_annotation()("serve/" + self.name, **self.args)
+        self._ann.__enter__()
+
+    def _close_slice(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use (the
+    registry and the Chrome tracer need no JAX)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class _NullSpan:
+    """What :meth:`Telemetry.span` returns with both sinks off."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Suspension:
+    __slots__ = ("_open",)
+
+    def __init__(self, opened: list):
+        self._open = opened
+
+    def __enter__(self) -> None:
+        for sp in reversed(self._open):
+            sp._close_slice()
+
+    def __exit__(self, exc_type, *rest) -> None:
+        if exc_type is None:
+            for sp in self._open:
+                sp._open_slice()
 
 
 # ---------------------------------------------------------------------------

@@ -168,6 +168,75 @@ def test_tracer_ring_is_bounded_and_drops_are_counted():
     T.validate_chrome_trace(trace, require_phases="iM")
 
 
+# ---------------------------------------------------------------------------
+# Span API (Chrome sink + profiler sink)
+# ---------------------------------------------------------------------------
+
+def test_span_with_both_sinks_off_is_one_null_context(monkeypatch):
+    tel = T.Telemetry(enabled=False)
+
+    def no_clock():
+        raise AssertionError("a disabled span read the clock")
+
+    monkeypatch.setattr(tel.tracer, "now", no_clock)
+    spans = [tel.span("schedule", round=1, step=0), tel.span("emit"),
+             tel.span("segment", profile=False)]
+    assert all(sp is T.NULL_SPAN for sp in spans)
+    with tel.span("harvest", round=2) as sp:
+        sp.set(steps=8)
+        with tel.suspended():
+            pass
+    assert tel.suspended() is T.NULL_SPAN
+    assert tel.tracer.events() == [] and tel.tracer.n_recorded == 0
+
+
+def test_span_records_chrome_event_with_args():
+    tel = T.Telemetry(enabled=True)
+    with tel.span("segment", cat="pool", profile=False, step=3) as sp:
+        sp.set(steps=8)
+    with tel.span("emit", chrome=False, round=4, step=32):
+        pass                            # profiler-only: no Chrome event
+    assert tel.span("emit", chrome=False) is T.NULL_SPAN
+    with tel.span("cow_copy", cat="pool", rid=1):
+        with tel.suspended():           # cuts profiler slices only
+            pass
+    evs = tel.tracer.events()
+    assert [e["name"] for e in evs] == ["segment", "cow_copy"]
+    seg = evs[0]
+    assert seg["ph"] == "X" and seg["cat"] == "pool"
+    assert seg["args"] == {"step": 3, "steps": 8}
+    assert evs[1]["args"] == {"rid": 1}
+    assert all(e["dur"] >= 0 for e in evs)
+    T.validate_chrome_trace(tel.tracer.to_chrome(), require_phases="X",
+                            require_names={"segment", "cow_copy"})
+
+
+def test_span_profiler_annotation_carries_args(tmp_path):
+    """Under jax.profiler on the CPU, a span is a host event named
+    serve/<name> whose stats carry its args; a suspension cuts it into
+    slices, and profile=False keeps a span out of the profile."""
+    import glob
+    from jax.profiler import ProfileData
+    tel = T.Telemetry(enabled=False, profiler_annotations=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("schedule", round=5, step=40):
+            jax.numpy.ones(4).block_until_ready()
+            with tel.suspended():
+                pass
+        with tel.span("segment", profile=False, step=40):
+            pass
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = sorted((ev for plane in ProfileData.from_file(path[0]).planes
+                     for line in plane.lines for ev in line.events
+                     if ev.name.startswith("serve/")),
+                    key=lambda ev: ev.start_ns)
+    assert [ev.name for ev in events] == ["serve/schedule"] * 2
+    assert all({k: v for k, v in ev.stats} == {"round": 5, "step": 40}
+               for ev in events)
+    assert events[0].start_ns + events[0].duration_ns <= events[1].start_ns
+    assert tel.tracer.events() == []
+
+
 def test_disabled_tracer_records_nothing():
     tr = T.Tracer(enabled=False)
     tr.instant("x")
@@ -338,15 +407,18 @@ def test_registry_matches_event_stream_e2e(dense_setup, tmp_path, int8,
     assert snap["serve_ttft_seconds"]["count"] == ttft_h.count
 
 
-def test_disabled_telemetry_is_token_identical(dense_setup):
+@pytest.mark.parametrize("annotations", [False, True])
+def test_disabled_telemetry_is_token_identical(dense_setup, annotations):
     """Acceptance: telemetry off produces bit-identical token streams —
     the tracer and rings go quiet, the registry stays live (back-compat
-    reads keep working)."""
+    reads keep working).  Profiler annotations on the instrumented engine
+    change nothing either."""
     cfg, params = dense_setup
     kw = dict(max_batch=3, kv_blocks=9, block_size=4, max_blocks_per_req=8,
               segment_len=4, seq_bucket=8)
     reqs = _reqs(cfg, 4)
-    ce_on = ContinuousEngine(params, cfg, **kw)
+    ce_on = ContinuousEngine(params, cfg, profiler_annotations=annotations,
+                             **kw)
     ce_off = ContinuousEngine(params, cfg, telemetry=False, **kw)
     key = jax.random.PRNGKey(3)
     res_on = ce_on.run(reqs, key=key, temperature=0.8)
