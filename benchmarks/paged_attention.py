@@ -31,14 +31,30 @@ headline fields are the fused/gather ratios and the bytes model, which
 transfer.  ``--check`` asserts the CI gate: fused decode tok/s >= the
 gather-dense (full) path at every occupancy >= 50%.
 
+``--kernel-scan`` replaces both with the decode kernel alone on a TPU:
+``paged_attention_kernel`` over each ``--pages-per-step`` (0 = the
+autotuner's) at each ``--widths`` table width, ``--layers`` back-to-back
+calls per jitted program, reported per call with the share of the least
+time of the live pages' bytes (HBM bandwidth from ``bench/peaks.json``).
+Row contexts fill the table, or with ``--traffic`` are a prompt plus a
+uniform share of an output drawn from a traffic file's length
+distributions (``bench/traffic/*.json``); pages are scattered over the
+pool and dead table entries point at null block 0.  A kernel without
+``pages_per_step`` (one page per grid step) is timed once per width.
+
 Usage:
   PYTHONPATH=src python benchmarks/paged_attention.py --smoke --check \
       --out BENCH_PR4.json
+  PYTHONPATH=src python benchmarks/paged_attention.py --kernel-scan \
+      --batch 32 --kv-heads 8 --groups 4 --head-dim 128 --kv-blocks 1498 \
+      --traffic bench/traffic/chat-poisson.json --pages-per-step 1 2 4 8 0
 """
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +62,11 @@ import numpy as np
 
 from repro.core import quant
 from repro.kernels import autotune
+from repro.kernels.paged_attention import ops as pops
+from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.models import attention as attn_lib
+
+PEAKS = pathlib.Path(__file__).resolve().parents[1] / "bench" / "peaks.json"
 
 
 def build_pool(key, *, kv_blocks, block_size, kvh, head_dim, int8):
@@ -86,11 +106,10 @@ def kv_bytes_per_step(pages_touched, block_size, kvh, head_dim, int8):
 
 
 def fused_pages_touched(n_valid, block_size, nbr):
-    """Pages the fused kernel fetches per request: the index map clamps
-    every dead table-tail entry to the last live page (repeated indices
-    elide the DMA), so the walk touches min(ceil(n_valid / BS), nbr)
-    distinct pages — evaluated at the ACTUAL table width, so a regression
-    to full-table walking shows up as pool-size-dependent bytes."""
+    """Pages the fused kernel fetches per request: it copies no page past
+    a row's live length, so the walk touches min(ceil(n_valid / BS), nbr)
+    pages — evaluated at the ACTUAL table width, so a regression to
+    full-table walking shows up as pool-size-dependent bytes."""
     return int(sum(min(-(-int(v) // block_size), nbr) for v in n_valid))
 
 
@@ -170,6 +189,88 @@ def attention_scan(args):
                   f"tight {rows[-1]['tok_s_gather_tight']:9.1f}  "
                   f"fused {rows[-1]['tok_s_fused']:9.1f}  "
                   f"(x{rows[-1]['speedup_fused_vs_full']:.2f} vs full)")
+    return rows
+
+
+def draw_contexts(rng, batch, cap, traffic):
+    """Per-row live tokens, clipped to the table's `cap` tokens: the whole
+    table, or a prompt plus a uniform share of an output drawn from the
+    traffic file's lognormal length distributions."""
+    if traffic is None:
+        return np.full((batch,), cap, np.int32)
+
+    def draw(d):
+        x = rng.lognormal(np.log(d["median"]), d["sigma"], batch)
+        return np.clip(x, d["min"], d["max"])
+    ctx = draw(traffic["prompt"]) + rng.uniform(0, 1, batch) \
+        * draw(traffic["output"])
+    return np.clip(ctx, 1, cap).astype(np.int32)
+
+
+def kernel_inputs(args, width, rng):
+    """The kernel's operands at one table width, and the live pages."""
+    bs, kvh = args.block_size, args.kv_heads
+    nv = draw_contexts(rng, args.batch, width * bs, args.traffic)
+    live = -(-nv // bs)
+    tables = np.zeros((args.batch, width), np.int32)
+    for r in range(args.batch):
+        tables[r, :live[r]] = rng.integers(1, args.kv_blocks, live[r])
+    int8 = args.dtype == "int8"
+    pk, pv = build_pool(jax.random.PRNGKey(args.seed),
+                        kv_blocks=args.kv_blocks, block_size=bs, kvh=kvh,
+                        head_dim=args.head_dim, int8=int8)
+    if not int8:
+        pk, pv = pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16)
+    (kq, ks), (vq, vs) = pops._split_pages(pk), pops._split_pages(pv)
+    q = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
+                          (args.batch, kvh, args.groups, args.head_dim),
+                          jnp.bfloat16)
+    return (q, kq, vq, ks, vs, jnp.asarray(tables), jnp.asarray(nv)), \
+        int(live.sum())
+
+
+def kernel_scan(args):
+    """Per-call device time of the decode kernel alone, per pages_per_step
+    and table width (one JSON row each)."""
+    dev = jax.devices()[0]
+    peaks = json.loads(PEAKS.read_text())["devices"].get(dev.device_kind, {})
+    grouped = "pages_per_step" in inspect.signature(
+        paged_attention_kernel).parameters
+    rng = np.random.default_rng(args.seed)
+    rows = []
+    for width in args.widths:
+        ops, live = kernel_inputs(args, width, rng)
+        for p in args.pages_per_step if grouped else [None]:
+            if p == 0:
+                _, p = pops.decode_grouping(args.batch, ops[1], width,
+                                            args.groups)
+            kw = {} if p is None else {"pages_per_step": p}
+            interpret = jax.default_backend() != "tpu"    # CPU: structural
+
+            def layers(q, *rest, kw=kw):
+                def body(_, q):
+                    acc, _m, _l = paged_attention_kernel(
+                        q, *rest, interpret=interpret, **kw)
+                    return q + (1e-6 * acc.sum()).astype(q.dtype)
+                return jax.lax.fori_loop(0, args.layers, body, q)
+            fn = jax.jit(layers)
+            us = autotune.time_median_us(lambda: fn(*ops), args.iters) \
+                / args.layers
+            # K + V pages (int8: one byte a value plus a bf16 scale a row).
+            per_row = (2 * args.head_dim + 2 * 2 if args.dtype == "int8"
+                       else 2 * args.head_dim * 2)
+            nbytes = live * args.block_size * args.kv_heads * per_row
+            row = {"device_kind": dev.device_kind, "dtype": args.dtype,
+                   "batch": args.batch, "kv_heads": args.kv_heads,
+                   "groups": args.groups, "block_size": args.block_size,
+                   "width": width, "pages_per_step": kw.get(
+                       "pages_per_step"), "call_us": us,
+                   "live_pages": live}
+            if "hbm_bytes_per_s" in peaks:
+                row["bytes_roofline_pct"] = \
+                    100 * nbytes / peaks["hbm_bytes_per_s"] / (us / 1e6)
+            print(json.dumps(row), flush=True)
+            rows.append(row)
     return rows
 
 
@@ -267,6 +368,22 @@ def main() -> None:
                     help="run the --check assertions against an existing "
                     "report instead of re-benchmarking (CI re-asserts the "
                     "bench-smoke artifact this way)")
+    ap.add_argument("--kernel-scan", action="store_true",
+                    help="time the decode kernel alone (TPU) instead of "
+                    "the occupancy scan and the serve delta")
+    ap.add_argument("--pages-per-step", type=int, nargs="+", default=[1, 0],
+                    help="kernel scan: pages per grid step (0 = the "
+                    "autotuner's)")
+    ap.add_argument("--widths", type=int, nargs="+", default=[64, 128],
+                    help="kernel scan: block-table widths")
+    ap.add_argument("--dtype", choices=("int8", "bf16"), default="int8",
+                    help="kernel scan: pool dtype")
+    ap.add_argument("--traffic", default=None, metavar="JSON",
+                    help="kernel scan: draw row contexts from this traffic "
+                    "file's prompt and output lengths")
+    ap.add_argument("--layers", type=int, default=36,
+                    help="kernel scan: calls per timed program")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_PR4.json")
     args = ap.parse_args()
     if args.smoke:
@@ -276,6 +393,15 @@ def main() -> None:
     if args.check_file:
         with open(args.check_file) as f:
             run_check(json.load(f)["occupancy_scan"])
+        return
+    if args.kernel_scan:
+        if args.traffic:
+            with open(args.traffic) as f:
+                args.traffic = json.load(f)
+        rows = kernel_scan(args)
+        with open(args.out, "w") as f:
+            json.dump({"bench": "paged_attention_kernel", "rows": rows}, f,
+                      indent=2)
         return
 
     rows = attention_scan(args)

@@ -37,15 +37,22 @@ _TABLE: dict[tuple[int, int, int, str], tuple[int, int, int]] = {}
 _MEASURED: set[tuple[int, int, int, str]] = set()
 
 # Paged-attention decode shapes: (batch_bucket, kvh, width, block_size,
-# head_dim, groups, dtype) -> kv_splits.  The tuned axes are the split
-# count and, implicitly, pages-per-program = ceil(width / kv_splits): each
-# kernel program walks one split's slice of the block table sequentially,
-# so more splits trade sequential page walking for cross-core parallelism
-# (and a slightly larger logsumexp merge).  The key is shape-complete
-# (head_dim and GQA group count included, like the matmul table's (m, k,
-# n)) so dumped fleet tables never collide across models.
+# head_dim, groups, dtype) -> kv_splits.  The kernel walks each split's
+# slice of every row's block table as one chain of grid steps; the pages
+# it takes a step follow from the shape (:func:`heuristic_pages_per_step`)
+# and are not tuned.  The key is shape-complete (head_dim and GQA group
+# count included, like the matmul table's (m, k, n)) so dumped fleet
+# tables never collide across models.
 _PAGED_TABLE: dict[tuple[int, int, int, int, int, int, str], int] = {}
 _PAGED_MEASURED: set[tuple[int, int, int, int, int, int, str]] = set()
+
+# Table tokens a decode grid step takes: the 128 lanes of its [G, P*BS]
+# score tile.  On a v5e the kernel alone is fastest there for int8 and
+# bf16 pools, 8 and 32 KV heads, blocks 16 and 32; a fixed byte budget
+# a step fits only some of them.  The bytes of one operand's pages a
+# step holds are capped, for VMEM (two slots and an f32 copy of each).
+PAGED_STEP_TOKENS = 128
+PAGED_STEP_BYTES = 512 << 10
 
 # Chunked-prefill shapes: (batch_bucket, kvh, block_size, head_dim, groups,
 # dtype) -> prefill chunk length.  The tuned axis is the tokens-per-chunk
@@ -191,7 +198,7 @@ def measure(m: int, k: int, n: int, dtype=jnp.int8, *,
 
 
 # ---------------------------------------------------------------------------
-# Paged-attention decode: kv_splits / pages-per-program
+# Paged-attention decode: kv_splits / pages_per_step
 # ---------------------------------------------------------------------------
 
 def _paged_key(batch: int, kvh: int, width: int, block_size: int,
@@ -201,30 +208,41 @@ def _paged_key(batch: int, kvh: int, width: int, block_size: int,
             int(head_dim), int(groups), jnp.dtype(dtype).name)
 
 
-def heuristic_paged_splits(batch: int, kvh: int, width: int,
-                           block_size: int, dtype=jnp.int8) -> int:
-    """Split count from the decode shape alone.
+def heuristic_pages_per_step(width: int, kv_splits: int, block_size: int,
+                             kvh: int, head_dim: int,
+                             dtype=jnp.int8) -> int:
+    """Table columns per decode grid step: the largest power of two whose
+    pages hold at most :data:`PAGED_STEP_TOKENS` tokens and, of one
+    operand, :data:`PAGED_STEP_BYTES`, and that fits the split's width:
+    8 pages at block 16 and 4 at block 32 for the served heads of 128."""
+    page = block_size * kvh * head_dim * jnp.dtype(dtype).itemsize
+    split_width = -(-width // max(1, min(kv_splits, width)))
+    p = 1
+    while (2 * p * block_size <= PAGED_STEP_TOKENS
+           and 2 * p * page <= PAGED_STEP_BYTES and 2 * p <= split_width):
+        p *= 2
+    return p
 
-    (batch x kv_heads) programs already run in parallel; splits only add
-    value when that grid underfills the cores, so target ~8 concurrent
-    programs and never split below one page per program."""
-    del block_size, dtype
-    par = max(1, batch * kvh)
-    want = max(1, -(-8 // par))
-    return min(width, next_pow2(want))
 
+def choose_paged_decode(batch: int, kvh: int, width: int, block_size: int,
+                        dtype=jnp.int8, *, head_dim: int, groups: int = 1,
+                        kv_splits: int | None = None) -> tuple[int, int]:
+    """``(kv_splits, pages_per_step)`` for one paged decode shape.
 
-def choose_paged_splits(batch: int, kvh: int, width: int, block_size: int,
-                        dtype=jnp.int8, *, head_dim: int = 0,
-                        groups: int = 1) -> int:
-    """kv_splits for one paged decode shape: measured when available,
-    else the deterministic heuristic (memoized, like choose_blocks)."""
-    key = _paged_key(batch, kvh, width, block_size, head_dim, groups,
-                     dtype)
-    if key not in _PAGED_TABLE:
-        _PAGED_TABLE[key] = heuristic_paged_splits(batch, kvh, width,
-                                                   block_size, dtype)
-    return _PAGED_TABLE[key]
+    ``kv_splits`` is the caller's if given, else the measured entry
+    (:func:`measure_paged`), else 1.  One split is the default because
+    the kernel's grid is ``(kv_splits, B, groups)`` and only the split
+    axis is parallel; within a split every row of the batch runs as one
+    chain, each step copying the next step's pages while it computes.  So
+    a split adds parallelism only across TensorCores (two on a megacore
+    chip, one on a v5e) and costs a chain restart and a wider merge on
+    any chip.  ``pages_per_step`` is always the heuristic's at that split
+    count."""
+    if kv_splits is None:
+        kv_splits = _PAGED_TABLE.get(_paged_key(
+            batch, kvh, width, block_size, head_dim, groups, dtype), 1)
+    return kv_splits, heuristic_pages_per_step(
+        width, kv_splits, block_size, kvh, head_dim, dtype)
 
 
 def record_paged(batch: int, kvh: int, width: int, block_size: int, dtype,
@@ -251,10 +269,11 @@ def measure_paged(batch: int, kvh: int, width: int, block_size: int,
                   dtype=jnp.int8, *, head_dim: int = 64, groups: int = 2,
                   candidates: Iterable[int] | None = None, iters: int = 3,
                   backend: str | None = None) -> tuple[int, dict]:
-    """Time `paged_attention` over candidate split counts on a synthetic
-    pool; record + return the best.  On CPU this times the vectorized
-    emulation (structural); on TPU the compiled kernel.  Returns
-    ``(best_kv_splits, {kv_splits: median_us})``."""
+    """Time `paged_attention` over candidate split counts (each at the
+    heuristic's pages per step) on a synthetic pool; record + return the
+    best.  On CPU this times the vectorized emulation (structural); on
+    TPU the compiled kernel.  Returns ``(best_kv_splits, {kv_splits:
+    median_us})``."""
     import jax
 
     from repro.kernels.paged_attention import ops as pops  # lazy: no cycle

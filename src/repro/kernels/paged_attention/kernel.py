@@ -10,43 +10,63 @@ ever materialized and no dequantized fp copy of the pool ever touches HBM;
 compare ``attention.attend_decode_paged``'s gather-then-attend reference,
 which pays both per decode step per layer.
 
-Layout (flash decoding, split-KV):
+Layout (flash decoding, split-KV, page groups):
 
-* grid ``(B, kv_splits, pages_per_split)`` — the innermost dimension
-  walks one split's slice of the request's block table sequentially
-  ("arbitrary"); batch / split are parallel.
-* Each step fetches one WHOLE page, ``(1, BS, KVH, D)``, and loops over
-  the KV heads inside the kernel.  Mosaic requires a block's last two dims
-  to be (8, 128)-divisible or whole, so a per-head ``(1, BS, 1, D)`` block
-  of the ``[NB, BS, KVH, D]`` pool cannot compile for a TPU; the whole
-  page can, and each page is DMA'd once instead of once per head.
-* The block tables and per-request lengths ride in as **scalar prefetch**
-  (``PrefetchScalarGridSpec``): the page index map reads
-  ``block_tables[b, split*P + p]`` before the body runs, so the pipeline
-  DMAs exactly the referenced page — pages are fetched through the table
-  indirection, never through a gathered copy.
-* Pages past the request's live length are **clamped to the last live
-  page** in the index map.  Consecutive grid steps with an identical block
-  index skip the re-fetch, so HBM traffic per request scales with its live
-  tokens, not with the pool size or the table width; the clamped steps'
-  compute is skipped with ``pl.when``.
-* Each program keeps a per-head ``(m, l, acc)`` carry in VMEM scratch and
-  emits its split's partial ``(acc, m, l)``; the cross-split combine is a
-  tiny logsumexp merge done by the wrapper (:func:`..ops.merge_splits`).
+* grid ``(kv_splits, B, ceil(W_split / P))``.  A split is a slice of
+  every row's block table (``W_split = ceil(W / kv_splits)`` columns); a
+  grid step handles one *group* of ``P`` consecutive table columns of one
+  row (``pages_per_step``, chosen from the page bytes and the width by
+  :func:`repro.kernels.autotune.choose_paged_decode`).  The split axis is
+  the one parallel axis; within a split the rows and groups run in order
+  on one core, as one chain.
+* **Manual DMA.**  The K/V pools (and the int8 scale pools) stay in HBM
+  (``memory_space=ANY``).  A step copies each live page of its group
+  ``pool[block_tables[b, col]]`` — the table read from scalar-prefetched
+  SMEM — into slot ``[P, BS, KVH, D]`` of a two-slot VMEM buffer, one
+  async copy per page and operand, all on the slot's DMA semaphore.
+  Whole pages: Mosaic requires a block's last two dims to be
+  (8, 128)-divisible or whole, so the ``[NB, BS, KVH, D]`` pool moves by
+  whole pages and the kernel loops over the KV heads.
+* **Double buffering.**  Before computing its group, a step starts the
+  copies of the chain's next live group into the other slot — the row's
+  next group, or the first group of the next row whose slice is live —
+  so the next group's pages stream in while this one computes, across
+  row boundaries.  Only a split's first live group copies its own pages.
+  Two SMEM scalars carry the chain: the slot in use and the step whose
+  copies were started into it.
+* **Dead groups and pages.**  A group that starts past the row's live
+  length (or past the table) issues no copy and runs no compute: its step
+  is an empty grid step.  In the row's last, partly live group only the
+  live pages are copied; the rest of the slot holds stale bytes, which the
+  position mask keeps out of the scores and out of V (int8: their scales
+  are zeroed; fp: their rows).
+* **Compute.**  The group is converted to f32 once, into VMEM scratch, so
+  a head's ``[P*BS, D]`` rows are a sublane-strided load of whole f32
+  tiles.  Each KV head then takes the whole group at once: one
+  ``[G, D] x [D, P*BS]`` score dot and one ``[G, P*BS] x [P*BS, D]``
+  value dot, with a per-head ``(m, l, acc)`` online-softmax carry in VMEM
+  scratch.  Each ``(split, row)`` emits its partial ``(acc, m, l)``; the
+  cross-split combine is a tiny logsumexp merge done by the wrapper
+  (:func:`..ops.merge_splits`).
 
-The int8 variant streams the page's int8 codes plus its ``[BS, KVH]``
-per-token-head scale tile and dequantizes in-registers (KIVI-style grid,
-identical to ``attention.dequantize_kv``).  Unlike the gather reference's
+The int8 variant copies the page's int8 codes plus its ``[BS, KVH]``
+per-token-head scale tile (lane-padded to 128 by the wrapper: Mosaic
+slices an HBM ref only along whole tiles) and dequantizes in-registers
+(KIVI-style grid, identical to ``attention.dequantize_kv``): the group's
+scale tiles, transposed to one row per head, scale the head's scores
+(K) and probabilities (V), ``(q . k) s_k`` and ``(p s_v) . v``, so no
+dequantized page is formed.  Unlike the gather reference's
 fully-integer path it keeps q and the probabilities in f32 — the int8 win
 here is HBM bytes, not MXU width — so parity with the int8 reference is
 close-not-bitwise (the reference additionally quantizes q and p; see
 tests/test_paged_attention.py).
 
-**Flash prefill** (:func:`flash_prefill_kernel`) extends the same layout
-to causal prompt chunks: grid ``(B, past_pages + 1 + chunk_pages)``, whole
-pages and an in-kernel head loop as above,
-first walks the request's past pages (identical scalar-prefetch
-indirection and dead-step clamping), then runs the causal self tile on
+**Flash prefill** (:func:`flash_prefill_kernel`) walks pages through
+BlockSpecs, one page per grid step: grid ``(B, past_pages + 1 +
+chunk_pages)``, whole pages and an in-kernel head loop as above,
+first walks the request's past pages (scalar-prefetch index maps, dead
+steps clamped to the last live page so repeated block indices elide the
+DMA), then runs the causal self tile on
 the in-hand chunk (kept fp, like the one-shot prefill's ``attend_full``),
 and finally QUANTIZES AND WRITES the chunk's K/V into its pool pages —
 the page writes are output index maps over the pool buffer itself
@@ -58,9 +78,9 @@ reproduces ``attention.quantize_kv`` bit-exactly (f32 absmax / 127,
 bf16-rounded scale), so chunked pools match ``pack_prompt``-packed pools.
 
 TPU notes: D is the 128-lane dim (head_dim 64/128) and KVH the sublane
-dim of every page block; G stays small — fine for VPU-bound decode.
+dim of every page; G stays small — fine for VPU-bound decode.
 tests/test_tpu_compile.py compiles both kernels for a described v5e at
-block sizes 16 and 32, fp and int8.  CPU CI runs the kernel in interpret
+block sizes 16 and 32, fp and int8.  CPU CI runs the kernels in interpret
 mode for parity only (per-grid-step interpreter overhead makes it slow);
 the fast CPU path is :func:`..ops.flash_decode_jnp` /
 :func:`..ops.flash_prefill_jnp`, the same math vectorized.
@@ -91,71 +111,150 @@ def _kernel(
     bt_ref,       # [B, W] int32  (scalar prefetch)
     nv_ref,       # [B]    int32  (scalar prefetch)
     q_ref,        # [1, KVH, G, D]
-    k_ref,        # [1, BS, KVH, D] (int8 or fp page, all kv heads)
-    *rest,        # (k_scale, v, v_scale | v), out, m, l, scratches
+    *rest,        # pools in HBM (k, [k_scale], v, [v_scale]), out, m, l,
+                  # their VMEM slots, DMA sems, chain state, carry
     bs: int,
-    pages_per_split: int,
+    pps: int,
+    ppg: int,
+    n_groups: int,
+    batch: int,
     width: int,
     d: int,
     kvh: int,
     int8: bool,
 ):
-    if int8:
-        ks_ref, v_ref, vs_ref = rest[0], rest[1], rest[2]
-        rest = rest[3:]
-    else:
-        v_ref = rest[0]
-        rest = rest[1:]
-    out_ref, m_ref, l_ref, acc_scr, m_scr, l_scr = rest
+    n_ops = 4 if int8 else 2
+    pools = rest[:n_ops]
+    out_ref, m_ref, l_ref = rest[n_ops:n_ops + 3]
+    slots = rest[n_ops + 3:2 * n_ops + 3]
+    sems, chain, kf_scr, vf_scr, acc_scr, m_scr, l_scr = \
+        rest[2 * n_ops + 3:]
 
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    p = pl.program_id(2)
+    s = pl.program_id(0)
+    b = pl.program_id(1)
+    i = pl.program_id(2)
+    col0 = s * pps                                  # split's first column
 
-    @pl.when(p == 0)
+    def row_end(r):
+        """One past the last live table column of row ``r`` in this
+        split: its live pages, clamped to the table and the split."""
+        nv = nv_ref[jnp.minimum(r, batch - 1)]
+        return jnp.minimum(jnp.minimum(pl.cdiv(nv, bs), width), col0 + pps)
+
+    def group_live(r, gi):
+        return (gi < n_groups) & (col0 + gi * ppg < row_end(r))
+
+    def copies(r, gi, slot):
+        """(live, async copy) for every page and operand of group ``gi``
+        of row ``r`` into ``slot``; pages past the row's end are dead."""
+        c0 = col0 + gi * ppg
+        end = row_end(r)
+        out = []
+        for j in range(ppg):
+            page = bt_ref[r, jnp.minimum(c0 + j, width - 1)]
+            for pool, buf in zip(pools, slots):
+                out.append((c0 + j < end, pltpu.make_async_copy(
+                    pool.at[page], buf.at[slot, j], sems.at[slot])))
+        return out
+
+    def start(r, gi, slot):
+        for live, cp in copies(r, gi, slot):
+            pl.when(live)(cp.start)
+
+    def next_live():
+        """The chain's next live step after ``(b, i)``: the row's next
+        group, else the first group of the next row with a live slice;
+        ``batch`` when none is left."""
+        def next_row():
+            return jax.lax.while_loop(
+                lambda r: (r < batch) & (row_end(r) <= col0),
+                lambda r: r + 1, b + 1)
+        return jax.lax.cond(group_live(b, i + 1),
+                            lambda: (b, i + 1),
+                            lambda: (next_row(), jnp.int32(0)))
+
+    @pl.when((b == 0) & (i == 0))
+    def _chain_reset():
+        chain[0] = 0          # slot of the current step's pages
+        chain[1] = -1         # flat id of the step started into it
+
+    @pl.when(i == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    page = s * pages_per_split + p
-    nv = nv_ref[b]
-    live = (page * bs < nv) & (page < width)
-
-    @pl.when(live)
+    @pl.when(group_live(b, i))
     def _step():
-        pos = page * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        valid = pos < nv                                        # [1, BS]
+        slot = chain[0]
+
+        @pl.when(chain[1] != b * n_groups + i)
+        def _first():                   # the chain's first live group
+            start(b, i, slot)
+
+        nb, ni = next_live()
+
+        @pl.when(nb < batch)
+        def _prefetch():
+            start(nb, ni, 1 - slot)
+            chain[0] = 1 - slot
+            chain[1] = nb * n_groups + ni
+
+        for live, cp in copies(b, i, slot):
+            pl.when(live)(cp.wait)
+
+        n = ppg * bs
+        base = (col0 + i * ppg) * bs
+        end_tok = jnp.minimum(nv_ref[b], row_end(b) * bs)
+        valid = base + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) \
+            < end_tok                                       # [1, P*BS]
+        # The group in f32, once for all heads: a head's rows are then
+        # a sublane-strided load of whole f32 tiles.
+        kf_scr[...] = slots[0][slot].astype(jnp.float32)
+        vf_scr[...] = slots[n_ops // 2][slot].astype(jnp.float32)
         if int8:
-            ks = ks_ref[0].astype(jnp.float32)                  # [BS, KVH]
-            vs = vs_ref[0].astype(jnp.float32)
+            # Scales as rows ([lanes, P*BS], head h in row h): K's scale
+            # the scores, V's the probabilities, in-register.  Dead
+            # pages' stale scales are zeroed; their int8 codes are finite.
+            k_rows = slots[1][slot].astype(jnp.float32).reshape(n, -1).T \
+                * (1 / np.sqrt(d))
+            v_rows = jnp.where(
+                valid, slots[3][slot].astype(jnp.float32).reshape(n, -1).T,
+                0.0)
+        else:
+            valid_rows = base + jax.lax.broadcasted_iota(
+                jnp.int32, (n, 1), 0) < end_tok             # [P*BS, 1]
         for h in range(kvh):
             q = q_ref[0, h].astype(jnp.float32)                 # [G, D]
-            k = k_ref[0, :, h, :].astype(jnp.float32)           # [BS, D]
-            v = v_ref[0, :, h, :].astype(jnp.float32)
-            if int8:
-                # In-register dequant: the page never exists in fp
-                # outside VMEM.
-                k = k * _col(ks, h)
-                v = v * _col(vs, h)
+            k = kf_scr[:, :, h, :].reshape(n, d)
+            v = vf_scr[:, :, h, :].reshape(n, d)
             srs = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) / np.sqrt(d)  # [G, BS]
+                preferred_element_type=jnp.float32)             # [G, n]
+            if int8:
+                srs = srs * k_rows[h:h + 1]
+            else:
+                srs = srs / np.sqrt(d)
+                # Dead pages of a partly live group hold stale slot
+                # bytes: masked out of the scores below, zeroed out of V.
+                v = jnp.where(valid_rows, v, 0.0)
             srs = jnp.where(valid, srs, NEG_INF)
             m_prev = m_scr[h]                                   # [G, 1]
             m_new = jnp.maximum(m_prev, srs.max(-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            # Explicit zeroing of masked probabilities: for a live page
+            # Explicit zeroing of masked probabilities: for a live group
             # m_new is a real score, so exp(NEG_INF - m_new) underflows to
             # 0 anyway — this just keeps fully-masked tails exact.
             prob = jnp.where(valid, jnp.exp(srs - m_new), 0.0)
             l_scr[h] = l_scr[h] * alpha + prob.sum(-1, keepdims=True)
+            if int8:
+                prob = prob * v_rows[h:h + 1]
             acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
                 prob, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[h] = m_new
 
-    @pl.when(p == pages_per_split - 1)
+    @pl.when(i == n_groups - 1)
     def _flush():
         out_ref[0, :, 0] = acc_scr[...]
         m_ref[0, :, 0] = m_scr[...]
@@ -163,7 +262,7 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("kv_splits", "interpret"))
+    jax.jit, static_argnames=("kv_splits", "pages_per_step", "interpret"))
 def paged_attention_kernel(
     q: jax.Array,             # [B, KVH, G, D] (any float dtype)
     k_pages: jax.Array,       # [NB, BS, KVH, D] fp or int8
@@ -174,11 +273,14 @@ def paged_attention_kernel(
     n_valid: jax.Array,       # [B] int32
     *,
     kv_splits: int = 1,
+    pages_per_step: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Split-KV partials ``(acc, m, l)`` with shapes
     ``([B,KVH,S,G,D], [B,KVH,S,G,1], [B,KVH,S,G,1])``; combine with
-    :func:`..ops.merge_splits`."""
+    :func:`..ops.merge_splits`.  ``pages_per_step`` table columns a grid
+    step (capped at the split width; ``ops.paged_attention`` takes the
+    autotuner's choice)."""
     b, kvh, g, d = q.shape
     _, bs, _, _ = k_pages.shape
     width = block_tables.shape[1]
@@ -186,54 +288,48 @@ def paged_attention_kernel(
     assert (k_scale is not None) == int8, "int8 pages need scales"
     ns = max(1, min(kv_splits, width))
     pps = -(-width // ns)
+    ppg = max(1, min(pages_per_step, pps))
+    n_groups = -(-pps // ppg)
 
-    def page_map(bi, si, pi, bt, nv):
-        gidx = si * pps + pi
-        # Clamp to the request's last live page: repeated block indices on
-        # consecutive steps elide the DMA, so dead table tail entries cost
-        # no HBM traffic (their compute is pl.when-skipped too).
-        live_last = jnp.maximum(jax.lax.div(nv[bi] - 1, bs), 0)
-        gidx = jnp.minimum(jnp.minimum(gidx, live_last), width - 1)
-        return (bt[bi, gidx], 0, 0, 0)
+    def q_map(si, bi, gi, bt, nv):
+        return (bi, 0, 0, 0)
 
-    def scale_map(bi, si, pi, bt, nv):
-        return page_map(bi, si, pi, bt, nv)[:3]
-
-    def out_map(bi, si, pi, bt, nv):
+    def out_map(si, bi, gi, bt, nv):
         return (bi, 0, si, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, kvh, g, d), lambda bi, si, pi, bt, nv:
-                     (bi, 0, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, d), page_map),
-    ]
-    args = [block_tables, n_valid, q, k_pages]
     if int8:
-        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
-        args.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, bs, kvh, d), page_map))
-    args.append(v_pages)
-    if int8:
-        in_specs.append(pl.BlockSpec((1, bs, kvh), scale_map))
-        args.append(v_scale)
-
+        # A page's [BS, KVH] scale tile is lane-padded in HBM, and Mosaic
+        # slices an HBM ref only along whole tiles: pad the lanes to 128
+        # (the bytes the tiled layout holds anyway) so a page is a slice.
+        lanes = ((0, 0), (0, 0), (0, -(-kvh // 128) * 128 - kvh))
+        pools = [k_pages, jnp.pad(k_scale, lanes),
+                 v_pages, jnp.pad(v_scale, lanes)]
+    else:
+        pools = [k_pages, v_pages]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, ns, pps),
-        in_specs=in_specs,
+        grid=(ns, b, n_groups),
+        in_specs=[pl.BlockSpec((1, kvh, g, d), q_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         out_specs=[
             pl.BlockSpec((1, kvh, 1, g, d), out_map),
             pl.BlockSpec((1, kvh, 1, g, 1), out_map),
             pl.BlockSpec((1, kvh, 1, g, 1), out_map),
         ],
-        scratch_shapes=[
+        scratch_shapes=[compat.VMEM((2, ppg, *p.shape[1:]), p.dtype)
+                        for p in pools] + [
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            compat.VMEM((ppg, bs, kvh, d), jnp.float32),
+            compat.VMEM((ppg, bs, kvh, d), jnp.float32),
             compat.VMEM((kvh, g, d), jnp.float32),
             compat.VMEM((kvh, g, 1), jnp.float32),
             compat.VMEM((kvh, g, 1), jnp.float32),
         ],
     )
-    kern = functools.partial(_kernel, bs=bs, pages_per_split=pps,
-                             width=width, d=d, kvh=kvh, int8=int8)
+    kern = functools.partial(_kernel, bs=bs, pps=pps, ppg=ppg,
+                             n_groups=n_groups, batch=b, width=width, d=d,
+                             kvh=kvh, int8=int8)
     acc, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -243,11 +339,13 @@ def paged_attention_kernel(
             jax.ShapeDtypeStruct((b, kvh, ns, g, 1), jnp.float32),
         ],
         compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # One chain per split: rows and groups run in order, because
+            # each step starts the copies of the next.
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_attention_decode",
-    )(*args)
+    )(block_tables, n_valid, q, *pools)
     return acc, m, l
 
 

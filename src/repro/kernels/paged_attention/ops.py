@@ -7,7 +7,8 @@ pool's native page pytrees — fp arrays or int8
 the split count from :mod:`repro.kernels.autotune`, and dispatches one of
 three backends:
 
-* ``"pallas"``    — the compiled TPU kernel (scalar-prefetch page walk).
+* ``"pallas"``    — the compiled TPU kernel (page groups copied by
+  manual DMA through the scalar-prefetched block tables).
 * ``"interpret"`` — the same kernel through the Pallas interpreter.  This
   is the CPU *correctness* path (CI parity tests); the interpreter costs
   ~1 ms per grid step, so it is not the CPU serving path.
@@ -21,9 +22,9 @@ three backends:
 ``backend=None`` resolves to ``"pallas"`` on TPU and ``"emulate"``
 elsewhere, mirroring ``cim_matmul``'s compiled-or-interpret selection.
 
-Traffic contract: with a width-``W`` block table the kernel touches only
-live pages (index-map clamping) and the emulate path gathers only the
-``W`` table columns it is handed — the serve loop truncates tables to a
+Traffic contract: with a width-``W`` block table the kernel copies only
+live pages (none past a row's length) and the emulate path gathers only
+the ``W`` table columns it is handed — the serve loop truncates tables to a
 power-of-two bucket of the live maximum each segment, so decode attention
 bytes scale with live tokens, never with ``kv_blocks``
 (benchmarks/paged_attention.py measures exactly this).
@@ -110,6 +111,19 @@ def flash_decode_jnp(q, k_pages, k_scale, v_pages, v_scale, block_tables,
     return merge_splits(acc, m, l)
 
 
+def decode_grouping(batch: int, k_pages, width: int, groups: int,
+                    kv_splits: int | None = None) -> tuple[int, int]:
+    """``(kv_splits, pages_per_step)`` that :func:`paged_attention` runs
+    for `batch` rows of `groups` query heads per KV head over a
+    width-`width` table into `k_pages` (any leading axes before ``[BS,
+    KVH, D]``, so a layer-stacked pool too)."""
+    codes, _ = _split_pages(k_pages)
+    bs, kvh, d = codes.shape[-3:]
+    return autotune.choose_paged_decode(batch, kvh, width, bs, codes.dtype,
+                                        head_dim=d, groups=groups,
+                                        kv_splits=kv_splits)
+
+
 def paged_attention(
     q: jax.Array,              # [B, 1, H, D]
     k_pages, v_pages,          # [NB, BS, KVH, D] arrays or QTensors
@@ -123,9 +137,10 @@ def paged_attention(
     :func:`repro.models.attention.attend_decode_paged` (same signature up
     to the keywords, same [B, 1, H, D] output).
 
-    ``kv_splits`` defaults to the autotuner's choice for this
-    (batch, kv_heads, table width, block size) — resolved here, outside
-    any jit boundary, like ``cim_matmul``'s block resolution.
+    ``kv_splits`` and the kernel's pages per grid step default to the
+    autotuner's choice for this (batch, kv_heads, table width, block
+    size) — resolved here, outside any jit boundary, like
+    ``cim_matmul``'s block resolution.
 
     ``n_valid`` is clamped to the table capacity ``W * BS`` (positions
     beyond the handed-in table do not exist); every backend applies the
@@ -134,15 +149,13 @@ def paged_attention(
     assert sq == 1, "paged flash decoding serves single-token queries"
     k_q, k_s = _split_pages(k_pages)
     v_q, v_s = _split_pages(v_pages)
-    bs = k_q.shape[1]
     kvh = k_q.shape[2]
     g = h // kvh
     width = block_tables.shape[1]
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "emulate"
-    if kv_splits is None:
-        kv_splits = autotune.choose_paged_splits(
-            b, kvh, width, bs, k_q.dtype, head_dim=d, groups=g)
+    kv_splits, pages_per_step = decode_grouping(b, k_pages, width, g,
+                                                kv_splits)
     qr = q.reshape(b, kvh, g, d)
     if backend == "emulate":
         out = flash_decode_jnp(qr, k_q, k_s, v_q, v_s, block_tables,
@@ -152,7 +165,8 @@ def paged_attention(
             qr, k_q, v_q, k_s, v_s,
             jnp.asarray(block_tables, jnp.int32),
             jnp.asarray(n_valid, jnp.int32),
-            kv_splits=kv_splits, interpret=backend == "interpret")
+            kv_splits=kv_splits, pages_per_step=pages_per_step,
+            interpret=backend == "interpret")
         out = merge_splits(acc, m, l)
     else:
         raise ValueError(f"backend must be 'pallas', 'interpret', or "

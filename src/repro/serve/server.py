@@ -128,6 +128,7 @@ import numpy as np
 
 from repro.core import backend as backend_lib
 from repro.kernels import autotune
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.models import model as model_lib
 from repro.serve import faults as faults_lib
 from repro.serve import kv_pool
@@ -398,6 +399,22 @@ class ContinuousEngine:
         # the segment span instead).
         with self.telemetry.span(name, chrome=False, **self._round_args):
             return self._launch(fn, *args)
+
+    def _segment_args(self, plan, rnd: dict, batch: int,
+                      width: int) -> dict:
+        """The dispatch span's args: the round's, plus the table width and,
+        with paged attention, the flash decode kernel's pages per grid
+        step (``ops.decode_grouping``, as the kernel chooses it).  Only
+        the profiler sink reads them, so without it they are the round's
+        alone."""
+        if not self.telemetry.profiler_annotations:
+            return rnd
+        args = dict(rnd, table_width=int(width))
+        if backend_lib.paged_attn_enabled(plan):
+            _, args["pages_per_step"] = paged_ops.decode_grouping(
+                batch, self.pages["k"], width,
+                self.cfg.n_heads // self.cfg.n_kv_heads)
+        return args
 
     def _launch(self, fn, *args):
         """Call a jitted program, counted as one dispatch."""
@@ -1586,7 +1603,9 @@ class ContinuousEngine:
             # work of the round falls between two phase spans.
             with tel.span("segment", profile=False, step=now,
                           index=rnd["round"], kind=kind) as seg_span:
+                self._round_args = self._segment_args(plan, rnd, mb, w)
                 outs = self._dispatch(fn, *args, name=kind + "_segment")
+                self._round_args = rnd
                 with tel.span("harvest", chrome=False, **rnd):
                     del args               # the old pool's last reference
                     (pages, tok_d, n_out_d, lens_d, done_d, failed_d, out_t,

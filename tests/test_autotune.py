@@ -1,5 +1,7 @@
 """Kernel block autotuner: determinism, pow2 bucketing, JSON round-trip,
 and the ops-layer aligned fast path / bucketed padding."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -126,3 +128,51 @@ def test_ops_aligned_shapes_skip_pad_and_slice():
     ref = cim_matmul_ref(a, w, a_s, ws, jnp.zeros((128,)), jnp.float32(1.0))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("kvh,bs,dtype,want", [
+    (8, 16, jnp.int8, 8),          # qwen3-8b int8 pool
+    (8, 32, jnp.int8, 4),
+    (32, 16, jnp.int8, 8),         # deepseek-7b: 32 KV heads
+    (8, 16, jnp.bfloat16, 8),
+    (8, 32, jnp.bfloat16, 4),
+    (32, 16, jnp.bfloat16, 4),     # 128 KiB pages: the byte cap decides
+])
+@pytest.mark.parametrize("width", [64, 128])
+def test_paged_decode_choice_at_served_shapes(kvh, bs, dtype, want, width):
+    """(kv_splits, pages_per_step) for the served decode shapes: one
+    split, and 128 tokens a step unless one operand's pages would pass
+    512 KiB."""
+    assert autotune.choose_paged_decode(32, kvh, width, bs, dtype,
+                                        head_dim=128, groups=4) == (1, want)
+
+
+def test_pages_per_step_never_exceeds_the_split_width():
+    for width in range(1, 70):
+        for splits in (1, 2, 3, 8):
+            for kvh, bs, hd in ((1, 4, 16), (8, 16, 128), (32, 32, 128)):
+                p = autotune.heuristic_pages_per_step(width, splits, bs, kvh,
+                                                      hd, jnp.int8)
+                assert 1 <= p <= -(-width // min(splits, width))
+                assert p & (p - 1) == 0
+        assert autotune.choose_paged_decode(
+            4, 2, width, 4, jnp.int8, head_dim=16)[1] <= width
+
+
+def test_decode_grouping_follows_the_split_count():
+    """``ops.decode_grouping`` (what the kernel runs and the engine's span
+    reports) reads a layer-stacked int8 pool's page shape, and its pages
+    per step follow whatever split count runs: a measured one, or the
+    caller's, never a stored pages-per-step."""
+    from repro.core import quant
+    from repro.kernels.paged_attention import ops as pops
+    codes = jnp.zeros((2, 4, 16, 8, 128), jnp.int8)      # [L,NB,BS,KVH,D]
+    pool = quant.QTensor(codes, jnp.ones((*codes.shape[:-1], 1),
+                                         jnp.bfloat16))
+    assert pops.decode_grouping(32, pool, 64, 4) == (1, 8)
+    assert pops.decode_grouping(32, pool, 64, 4, kv_splits=16) == (16, 4)
+    autotune.record_paged(32, 8, 64, 16, jnp.int8, 16, head_dim=128,
+                          groups=4)
+    assert pops.decode_grouping(32, pool, 64, 4) == (16, 4)
+    entry = json.loads(autotune.dump())["paged_entries"][0]
+    assert entry["kv_splits"] == 16 and "pages_per_step" not in entry

@@ -1,7 +1,7 @@
 """Fused paged-attention kernel: parity vs the gather reference (fp and
 int8), ragged lengths, null-block masking, GQA, split-KV equivalence,
-backend agreement (Pallas interpreter vs jnp emulation), autotuned splits,
-and the DeploymentPlan wiring."""
+backend agreement (Pallas interpreter vs jnp emulation), page groups per
+grid step, autotuned splits and groups, and the DeploymentPlan wiring."""
 import dataclasses
 
 import jax
@@ -12,6 +12,7 @@ import pytest
 from repro.core import backend as backend_lib
 from repro.core import quant
 from repro.kernels import autotune
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention import ref as paged_ref
 from repro.models import attention as A
@@ -212,6 +213,94 @@ def test_truncated_table_width_matches_full(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# Page groups: the kernel's pages_per_step against the emulation
+# ---------------------------------------------------------------------------
+
+def _scattered_pool(seed, *, bs, width, lens, int8, kvh=2, g=2, d=16):
+    """Pages of every row scattered over a pool whose null block 0 and
+    spare blocks hold garbage; dead table entries point at block 0.
+    Returns ``(q [B,KVH,G,D], k, k_scale, v, v_scale, tables, n_valid)``
+    in the kernel's operand form."""
+    rng = np.random.default_rng(seed)
+    b = len(lens)
+    live = [min(-(-n // bs), width) for n in lens]
+    nb = 1 + sum(live) + 3
+    perm = rng.permutation(np.arange(1, nb))
+    tables = np.zeros((b, width), np.int32)
+    nxt = 0
+    for r, n in enumerate(live):
+        tables[r, :n] = perm[nxt:nxt + n]
+        nxt += n
+    shape = (nb, bs, kvh, d)
+    q = jnp.asarray(rng.standard_normal((b, kvh, g, d)), jnp.float32)
+
+    def pool():
+        x = rng.standard_normal(shape).astype(np.float32)
+        if not int8:
+            x[0], x[perm[nxt:]] = 1e8, 1e8
+            return jnp.asarray(x), None
+        codes, scale = A.quantize_kv(jnp.asarray(x))
+        codes = codes.at[0].set(111)
+        scale = scale.at[0].set(1e4).at[perm[nxt:]].set(1e4)
+        return codes, scale
+
+    (k, ks), (v, vs) = pool(), pool()
+    return q, k, ks, v, vs, jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+
+
+def _grouped_vs_emulated(ops, kv_splits, pages_per_step):
+    q, k, ks, v, vs, tables, nv = ops
+    want = paged_ops.flash_decode_jnp(q, k, ks, v, vs, tables, nv,
+                                      kv_splits=kv_splits)
+    acc, m, l = paged_kernel.paged_attention_kernel(
+        q, k, v, ks, vs, tables, nv, kv_splits=kv_splits,
+        pages_per_step=pages_per_step, interpret=True)
+    got = paged_ops.merge_splits(acc, m, l)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("bs", [16, 32])
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4, 8])
+def test_grouped_kernel_matches_emulation(pages_per_step, bs, int8):
+    """Rows of length 0, 1, exactly one group, one past it and beyond the
+    table (clamped to it), over a width the group does not divide, with
+    dead entries on the garbage null block: the grouped kernel agrees
+    with the vectorized emulation."""
+    p = pages_per_step
+    width = 2 * p + 1
+    lens = [0, 1, p * bs, p * bs + 1, width * bs + 7]
+    ops = _scattered_pool(p * 10 + bs + int8, bs=bs, width=width, lens=lens,
+                          int8=int8)
+    _grouped_vs_emulated(ops, 1, p)
+
+
+@pytest.mark.parametrize("kv_splits,pages_per_step",
+                         [(2, 4), (4, 2), (2, 8)])
+def test_grouped_kernel_split_kv(kv_splits, pages_per_step):
+    """kv_splits > 1 with several pages a step: a split's last group
+    reaching into the next split (6 and 3 columns a split), and a group
+    capped at a split's width, merge to the emulation."""
+    width = 11
+    lens = [0, 7, 16 * 5, 16 * 6 + 3, 16 * width]
+    ops = _scattered_pool(kv_splits + pages_per_step, bs=16, width=width,
+                          lens=lens, int8=True)
+    _grouped_vs_emulated(ops, kv_splits, pages_per_step)
+
+
+def test_grouped_kernel_head_dim_120():
+    """A head_dim that is not a multiple of 128 runs through interpret
+    mode with the autotuner's grouping."""
+    ops = _scattered_pool(5, bs=16, width=8, lens=[33, 128, 90], int8=True,
+                          kvh=2, g=4, d=120)
+    p = autotune.heuristic_pages_per_step(8, 1, 16, 2, 120, ops[1].dtype)
+    assert p == 8
+    _grouped_vs_emulated(ops, 1, p)
+
+
+# ---------------------------------------------------------------------------
 # gather_pages tight bound (the kept reference stops scaling with the pool)
 # ---------------------------------------------------------------------------
 
@@ -244,25 +333,26 @@ def test_autotune_paged_heuristic_and_roundtrip(tmp_path):
     autotune.clear()
     try:
         # deterministic + memoized
-        s1 = autotune.choose_paged_splits(2, 2, 8, 4, jnp.int8, head_dim=16)
-        assert s1 == autotune.choose_paged_splits(2, 2, 8, 4, jnp.int8,
+        s1 = autotune.choose_paged_decode(2, 2, 8, 4, jnp.int8, head_dim=16)
+        assert s1 == autotune.choose_paged_decode(2, 2, 8, 4, jnp.int8,
                                                   head_dim=16)
-        # big batch*kvh -> no splitting; tiny -> splits, capped at width
-        assert autotune.heuristic_paged_splits(8, 8, 16, 4) == 1
-        assert autotune.heuristic_paged_splits(1, 1, 4, 4) <= 4
+        # one chain per split: the heuristic never splits
+        assert autotune.choose_paged_decode(8, 8, 16, 4,
+                                            head_dim=16)[0] == 1
+        assert autotune.choose_paged_decode(1, 1, 4, 4, head_dim=16)[0] == 1
         # measured entries override and survive a dump/load round trip;
         # the key is shape-complete, so another head_dim never collides
         autotune.record_paged(2, 2, 8, 4, jnp.int8, 4, head_dim=16)
-        assert autotune.choose_paged_splits(2, 2, 8, 4, jnp.int8,
-                                            head_dim=16) == 4
-        assert autotune.choose_paged_splits(2, 2, 8, 4, jnp.int8,
+        assert autotune.choose_paged_decode(2, 2, 8, 4, jnp.int8,
+                                            head_dim=16)[0] == 4
+        assert autotune.choose_paged_decode(2, 2, 8, 4, jnp.int8,
                                             head_dim=128) == s1
         path = tmp_path / "tune.json"
         autotune.dump(str(path))
         autotune.clear()
         assert autotune.load(str(path)) >= 1
-        assert autotune.choose_paged_splits(2, 2, 8, 4, jnp.int8,
-                                            head_dim=16) == 4
+        assert autotune.choose_paged_decode(2, 2, 8, 4, jnp.int8,
+                                            head_dim=16)[0] == 4
     finally:
         autotune.clear()
 
@@ -274,8 +364,8 @@ def test_autotune_measure_paged_smoke():
             2, 2, 4, 4, jnp.float32, head_dim=8, groups=2,
             candidates=(1, 2), iters=1, backend="emulate")
         assert best in timings and set(timings) == {1, 2}
-        assert autotune.choose_paged_splits(
-            2, 2, 4, 4, jnp.float32, head_dim=8, groups=2) == best
+        assert autotune.choose_paged_decode(
+            2, 2, 4, 4, jnp.float32, head_dim=8, groups=2)[0] == best
     finally:
         autotune.clear()
 
@@ -325,3 +415,32 @@ def test_attention_layer_paged_branch_fused_vs_reference():
                                   np.asarray(c_ref["k"]))
     np.testing.assert_array_equal(np.asarray(c_fus["v"]),
                                   np.asarray(c_ref["v"]))
+
+
+def test_kernel_scan_times_each_grouping(tmp_path):
+    """``benchmarks/paged_attention.py --kernel-scan`` (the kernel-alone
+    timing) runs end to end through the interpreter at a toy size: one row
+    per width and pages-per-step, 0 resolved to the autotuner's choice,
+    live pages drawn from a traffic file and bounded by the table."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    out = tmp_path / "scan.json"
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu")
+    subprocess.run(
+        [sys.executable, str(repo / "benchmarks" / "paged_attention.py"),
+         "--kernel-scan", "--batch", "2", "--kv-heads", "2", "--groups", "2",
+         "--head-dim", "128", "--kv-blocks", "24", "--widths", "2", "8",
+         "--layers", "1", "--iters", "1", "--pages-per-step", "1", "0",
+         "--traffic", str(repo / "bench" / "traffic" / "chat-poisson.json"),
+         "--out", str(out)], check=True, env=env, cwd=tmp_path,
+        capture_output=True)
+    rows = json.loads(out.read_text())["rows"]
+    assert [(r["width"], r["pages_per_step"]) for r in rows] == [
+        (2, 1), (2, 2), (8, 1), (8, 8)]
+    for r in rows:
+        assert r["call_us"] > 0
+        assert 2 <= r["live_pages"] <= 2 * r["width"]

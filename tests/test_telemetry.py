@@ -438,6 +438,37 @@ def test_disabled_telemetry_is_token_identical(dense_setup, annotations):
     assert len(ce_on.occupancy_trace) > 0
 
 
+def test_dispatch_span_names_the_decode_grouping(dense_setup, tmp_path):
+    """Under jax.profiler, each segment dispatch span of a paged-attention
+    engine carries its table width and the flash decode kernel's pages per
+    grid step, as the autotuner chooses them for that width."""
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.core import backend as backend_lib
+    from repro.kernels import autotune
+    cfg, params = dense_setup
+    plan = backend_lib.DeploymentPlan(default="exact", paged_attn=True)
+    ce = ContinuousEngine(params, cfg, plan=plan, max_batch=2, kv_blocks=12,
+                          block_size=4, segment_len=4, seq_bucket=8,
+                          profiler_annotations=True)
+    with jax.profiler.trace(str(tmp_path)):
+        ce.run(_reqs(cfg, 2, max_new=6))
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    stats = [dict(ev.stats) for plane in ProfileData.from_file(path[0]).planes
+             for line in plane.lines for ev in line.events
+             if ev.name in ("serve/decode_segment", "serve/mixed_segment")]
+    assert stats
+    kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    for st in stats:
+        w = st["table_width"]
+        assert st["pages_per_step"] == autotune.choose_paged_decode(
+            2, kvh, w, 4, jnp.float32, head_dim=hd,
+            groups=cfg.n_heads // kvh)[1]
+
+
 def test_reused_engine_resets_run_scope(dense_setup):
     """Back-to-back runs on ONE engine: run-scoped counters restart from
     zero (one reset, no drift), lifetime dispatch count accumulates."""

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import autotune
 from repro.kernels.cim_matmul import ops as mm_ops
 from repro.kernels.paged_attention import ops as pa_ops
 
@@ -92,3 +93,38 @@ def test_flash_prefill_compiles(sds, bs, int8):
         sds((B, CHUNK, KVH, HD), jnp.bfloat16), pk, pv,
         sds((B, W), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32))
 
+
+@pytest.mark.parametrize("w", [64, 128])
+@pytest.mark.parametrize("bs", [16, 32])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_grouped_flash_decode_compiles(sds, w, bs, int8):
+    """The served decode shape (32 rows, qwen3-8b heads) at the table
+    widths the chat traffic dispatches, with the autotuner's pages per
+    grid step."""
+    pk, pv = _pages(sds, bs, int8)
+    dtype = jnp.int8 if int8 else jnp.bfloat16
+    assert autotune.choose_paged_decode(
+        32, KVH, w, bs, dtype, head_dim=HD, groups=G) \
+        == (1, {16: 8, 32: 4}[bs])
+
+    def fn(q, pk, pv, t, n):
+        return pa_ops.paged_attention(q, pk, pv, t, n, backend="pallas")
+    _assert_tpu_kernel(fn, sds((32, 1, KVH * G, HD), jnp.bfloat16), pk, pv,
+                       sds((32, w), jnp.int32), sds((32,), jnp.int32))
+
+
+def test_grouped_flash_decode_compiles_32_kv_heads(sds):
+    """A deepseek-7b-like pool: 32 KV heads, one query head each, int8
+    pages of 16 tokens, eight pages a grid step."""
+    from repro.core import quant
+    shape = (NB, 16, 32, HD)
+    pk, pv = [quant.QTensor(sds(shape, jnp.int8),
+                            sds((*shape[:-1], 1), jnp.bfloat16))
+              for _ in range(2)]
+    assert autotune.choose_paged_decode(
+        32, 32, 128, 16, jnp.int8, head_dim=HD, groups=1) == (1, 8)
+
+    def fn(q, pk, pv, t, n):
+        return pa_ops.paged_attention(q, pk, pv, t, n, backend="pallas")
+    _assert_tpu_kernel(fn, sds((32, 1, 32, HD), jnp.bfloat16), pk, pv,
+                       sds((32, 128), jnp.int32), sds((32,), jnp.int32))
