@@ -3,8 +3,8 @@
 The least time the chip could take for a piece of work is the larger of
 its operations over the peak rate and its bytes over the HBM bandwidth
 (``peaks.json``).  A kernel's roofline share is that least time over the
-kernel's device time; ``mfu`` is the model's operations over the window
-times the int8 peak.
+kernel's device time; ``mfu`` is the model's operations (the adapter's
+``model_ops``) over the window times the int8 peak.
 """
 from __future__ import annotations
 
@@ -59,22 +59,3 @@ def w8a8_call(text: str) -> tuple[float, float] | None:
 def least_seconds(ops: float, nbytes: float, op_peak: float,
                   bw: float) -> float:
     return max(ops / op_peak, nbytes / bw)
-
-
-def matmul_params(cfg: dict) -> tuple[int, int]:
-    """(weights of one token's pass through the layers, of the head)."""
-    d, f = int(cfg["hidden_size"]), int(cfg["intermediate_size"])
-    h, kvh = int(cfg["num_attention_heads"]), int(cfg["num_key_value_heads"])
-    hd = int(cfg["head_dim"])
-    per_layer = d * h * hd * 2 + d * kvh * hd * 2 + 3 * d * f
-    return per_layer * int(cfg["num_hidden_layers"]), \
-        d * int(cfg["vocab_size"])
-
-
-def model_ops(cfg: dict, tokens: int, heads_out: int, ctx_sum: int) -> float:
-    """Operations of `tokens` token positions through the model, `heads_out`
-    of them through the output head, attending `ctx_sum` keys in all."""
-    layers_w, head_w = matmul_params(cfg)
-    attn = 4.0 * int(cfg["num_hidden_layers"]) \
-        * int(cfg["num_attention_heads"]) * int(cfg["head_dim"]) * ctx_sum
-    return 2.0 * layers_w * tokens + 2.0 * head_w * heads_out + attn

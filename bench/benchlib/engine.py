@@ -40,9 +40,12 @@ class Settings:
     preemption: str
 
 
-def settings(cfg, conf: dict, max_tokens: int) -> Settings:
+def settings(conf: dict, max_tokens: int, kv_layout: tuple[int, int, int]
+             ) -> Settings:
     """`max_tokens`: the longest prompt plus output the mix can draw (it
-    sizes the block tables, so every seed compiles the same programs)."""
+    sizes the block tables, so every seed compiles the same programs).
+    `kv_layout`: the adapter's (KV heads, head width, query heads per KV
+    head), which the engine's own prefill-chunk choice reads."""
     from repro.kernels import autotune
 
     eng = conf["engine"]
@@ -50,11 +53,10 @@ def settings(cfg, conf: dict, max_tokens: int) -> Settings:
     chunk = eng.get("prefill_chunk")
     if chunk is None:                # the engine's own choice
         import jax.numpy as jnp
-        kvh = cfg.n_kv_heads
+        kvh, hd, groups = kv_layout
         dtype = jnp.int8 if eng["kv_cache_dtype"] == "int8" else jnp.float32
-        chunk = autotune.choose_prefill_chunk(
-            mb, kvh, bs, dtype, head_dim=cfg.resolved_head_dim,
-            groups=cfg.n_heads // kvh)
+        chunk = autotune.choose_prefill_chunk(mb, kvh, bs, dtype,
+                                              head_dim=hd, groups=groups)
     return Settings(
         max_batch=mb, block_size=bs, segment_len=int(eng["segment_len"]),
         prefill_chunk=int(chunk), max_blocks_per_req=-(-max_tokens // bs),
@@ -152,16 +154,15 @@ class _Segment:
                 out_t, np.zeros((mb, self.seg_len), np.float32), np.int32(i))
 
 
-def shadow_programs(cfg, plan, s: Settings, kv_blocks: int, requests, *,
-                    open_after_steps: float, window_steps: int):
+def shadow_programs(stand_in_cfg, plan, s: Settings, kv_blocks: int,
+                    requests, *, open_after_steps: float, window_steps: int):
     """``([(program, step of its first dispatch), ...], window)``: the
     segment programs, in first-use order, that serving `requests` with
     these settings dispatches until `window_steps` steps after the window
     opens (by the harness's own rule), and the window as the step clock
     saw it (each request's arrival and admission step; no times).  Runs
-    on the host; the pool is a one-byte stand-in."""
-    import dataclasses as dc
-
+    on the host over `stand_in_cfg`, the adapter's ``stand_in_config``
+    of the model, whose pool is a byte a block."""
     import jax
 
     from benchlib import window as win
@@ -194,15 +195,13 @@ def shadow_programs(cfg, plan, s: Settings, kv_blocks: int, requests, *,
                 seen.setdefault(p, now[0])
             return fn(*args)
 
-    tiny = dc.replace(cfg, n_layers=1, n_heads=1, n_kv_heads=1, head_dim=1,
-                      d_model=1, d_ff=1)
     records = {r.rid: win.Req(r.rid, r.arrival_step, r.prompt_len,
                               r.max_new) for r in requests}
     d = win._Client(records, open_after_steps, float("inf"), None, None,
                     clock=lambda: 0.0)   # opens by steps alone
     horizon = None
     with jax.default_device(jax.devices("cpu")[0]):
-        eng = Shadow(None, tiny, plan=plan, max_batch=s.max_batch,
+        eng = Shadow(None, stand_in_cfg, plan=plan, max_batch=s.max_batch,
                      kv_blocks=kv_blocks, block_size=s.block_size,
                      max_blocks_per_req=s.max_blocks_per_req,
                      segment_len=s.segment_len, chunked_prefill=True,

@@ -1,41 +1,18 @@
-"""The system under test, as a configuration file asks for it.
+"""The system under test's weights and plan, for any architecture.
 
-Maps ``configs/<config>.json`` onto the program: its registry config with
-the published sizes checked and the file's settings applied, the
-deployment plan, and the engine's settings.  Also makes the weights: one
-jitted call from the seed, in the form they are served in (int8 codes
-with per-channel scales), laid out as the program's frozen parameter tree
-and, for the reference, as a plain dict of the same arrays.
+What is particular to an architecture (its configuration's mapping onto
+the program, its extra leaves, its reference's names, its operations) is
+in the adapter its configuration file names (``adapters/<name>.py``, see
+``benchlib/spec.py``).  Here: the deployment plan, the shapes of the
+program's frozen parameter tree, and the weights, made in one jitted call
+from the seed in the form they are served in (int8 codes with
+per-channel scales), leaf by leaf by the rule of each leaf's name.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 
-# published key -> program ModelConfig field
-WIDTHS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-          "num_attention_heads": "n_heads",
-          "num_key_value_heads": "n_kv_heads",
-          "num_hidden_layers": "n_layers", "vocab_size": "vocab"}
-
 CODE_STD = 73.61215932167728      # std of int8 codes uniform on [-127, 127]
-
-
-def program_config(conf: dict):
-    """The program's ``ModelConfig`` for the configuration file `conf`:
-    the registry entry's architecture with the file's sizes and settings
-    (the file holds the configuration as it is run)."""
-    from repro import configs
-    cfg = configs.get_config(conf["registry"])
-    sizes = {field: int(conf[key]) for key, field in WIDTHS.items()}
-    if int(conf["head_dim"]) != sizes["d_model"] // sizes["n_heads"]:
-        sizes["head_dim"] = int(conf["head_dim"])
-    return dataclasses.replace(
-        cfg, **sizes, qk_norm=bool(conf["qk_norm"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        kv_cache_dtype=conf["engine"]["kv_cache_dtype"])
 
 
 def deployment_plan(conf: dict):
@@ -64,9 +41,56 @@ def seed_words(seed: int):
 CHUNK_ELEMS = 1 << 26    # a leaf is made in slices of at most this size
 
 
-def _leaf(key, path: tuple, shape, dtype, sibling_k: int, a_scale: float):
-    """One leaf, made slice by slice along its first axis so that the
-    random bits of a large leaf never exist whole."""
+def _w_q(key, shape, dtype, sibling_k, a_scale):
+    """int8 weight codes, uniform on [-127, 127]."""
+    import jax
+    import jax.numpy as jnp
+    bits = jax.random.bits(key, shape, jnp.uint8)
+    codes = bits.astype(jnp.int16) - 128
+    return jnp.maximum(codes, -127).astype(jnp.int8)
+
+
+def _w_scale(key, shape, dtype, sibling_k, a_scale):
+    """Per-output-channel weight scales: unit-variance outputs for unit
+    inputs over the `sibling_k` rows of the codes, jittered by 20%."""
+    import jax
+    import jax.numpy as jnp
+    jit = jax.random.uniform(key, shape, jnp.float32, 0.8, 1.2)
+    return jit * (sibling_k ** -0.5 / CODE_STD)
+
+
+def _a_scale(key, shape, dtype, sibling_k, a_scale):
+    import jax.numpy as jnp
+    return jnp.full(shape, a_scale, dtype)
+
+
+def _norm_scale(key, shape, dtype, sibling_k, a_scale):
+    """RMSNorm gains."""
+    import jax
+    import jax.numpy as jnp
+    return jax.random.uniform(key, shape, jnp.float32, 0.9, 1.1)
+
+
+def _table(key, shape, dtype, sibling_k, a_scale):
+    """Token embedding, std d^-1/2."""
+    import jax
+    import jax.numpy as jnp
+    bits = jax.random.bits(key, shape, jnp.uint16).astype(jnp.float32)
+    return ((bits - 32767.5) * (shape[-1] ** -0.5 / 18918.61)).astype(dtype)
+
+
+# leaf name -> rule(key, shape, dtype, sibling_k, a_scale) -> array: how
+# each leaf of that name is made.  `sibling_k` is the contraction size of
+# the int8 matrix the leaf belongs to (``<x>_q`` for ``<x>_scale``, else
+# ``w_q`` beside it; 1 where there is none).  An adapter's
+# ``WEIGHT_RULES`` add rules for other names in the same form.
+COMMON_RULES = {"w_q": _w_q, "w_scale": _w_scale, "a_scale": _a_scale,
+                "scale": _norm_scale, "table": _table}
+
+
+def _leaf(key, rule, shape, dtype, sibling_k: int, a_scale: float):
+    """One leaf by `rule`, made slice by slice along its first axis so
+    that the random bits of a large leaf never exist whole."""
     import math
 
     import jax
@@ -78,84 +102,55 @@ def _leaf(key, path: tuple, shape, dtype, sibling_k: int, a_scale: float):
     if shape and rows is not None and rows < shape[0]:
         n = shape[0] // rows
         parts = jax.lax.map(
-            lambda i: _slice(jax.random.fold_in(key, i), path,
-                             (rows, *shape[1:]), dtype, sibling_k, a_scale),
+            lambda i: rule(jax.random.fold_in(key, i), (rows, *shape[1:]),
+                           dtype, sibling_k, a_scale),
             jnp.arange(n))
         return parts.reshape(shape)
-    return _slice(key, path, shape, dtype, sibling_k, a_scale)
+    return rule(key, shape, dtype, sibling_k, a_scale)
 
 
-def _slice(key, path: tuple, shape, dtype, sibling_k: int, a_scale: float):
-    import jax
-    import jax.numpy as jnp
-    name = path[-1]
-    if name == "w_q":
-        bits = jax.random.bits(key, shape, jnp.uint8)
-        codes = bits.astype(jnp.int16) - 128
-        return jnp.maximum(codes, -127).astype(jnp.int8)
-    if name == "w_scale":
-        jit = jax.random.uniform(key, shape, jnp.float32, 0.8, 1.2)
-        return jit * (sibling_k ** -0.5 / CODE_STD)
-    if name == "a_scale":
-        return jnp.full(shape, a_scale, dtype)
-    if name == "scale":                      # RMSNorm gains
-        return jax.random.uniform(key, shape, jnp.float32, 0.9, 1.1)
-    if name == "table":                      # token embedding, std d^-1/2
-        bits = jax.random.bits(key, shape, jnp.uint16).astype(jnp.float32)
-        return ((bits - 32767.5) * (shape[-1] ** -0.5 / 18918.61)
-                ).astype(dtype)
-    raise ValueError(f"no weight rule for {'/'.join(path)}")
-
-
-def weights_fn(shapes, a_scale: float):
+def weights_fn(shapes, a_scale: float, rules: dict):
     """The jitted ``make(lo, hi)`` that builds the frozen parameter tree
     `shapes` from the two words of a seed (:func:`seed_words`): every leaf
-    made on the device in one call, in its served dtype."""
+    made on the device in one call, in its served dtype, by the rule of
+    its name (``COMMON_RULES`` and the adapter's `rules`)."""
     import jax
 
+    clash = sorted(COMMON_RULES.keys() & rules.keys())
+    if clash:
+        raise ValueError(f"adapter weight rules for {clash} would replace "
+                         "the common rules")
+    rules = {**COMMON_RULES, **rules}
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [tuple(getattr(k, "key", str(k)) for k in p) for p, _ in flat]
     by_path = dict(zip(paths, (x for _, x in flat)))
 
+    def rule_of(path):
+        if path[-1] not in rules:
+            raise ValueError(f"no weight rule for {'/'.join(path)}")
+        return rules[path[-1]]
+
     def k_of(path):
-        w = by_path.get(path[:-1] + ("w_q",))
+        name = path[-1]
+        mat = name[:-len("_scale")] + "_q" if name.endswith("_scale") \
+            else "w_q"
+        w = by_path.get(path[:-1] + (mat,))
         return int(w.shape[-2]) if w is not None else 1
+
+    leaf_rules = [rule_of(path) for path in paths]
 
     def make(lo, hi):
         base = jax.random.fold_in(jax.random.fold_in(
             jax.random.PRNGKey(0x5EED), lo), hi)
-        leaves = [_leaf(jax.random.fold_in(base, i), path, x.shape, x.dtype,
+        leaves = [_leaf(jax.random.fold_in(base, i), rule, x.shape, x.dtype,
                         k_of(path), a_scale)
-                  for i, (path, (_, x)) in enumerate(zip(paths, flat))]
+                  for i, (path, rule, (_, x)) in enumerate(
+                      zip(paths, leaf_rules, flat))]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return jax.jit(make)
 
 
-def make_weights(shapes, seed: int, a_scale: float):
+def make_weights(shapes, seed: int, a_scale: float, rules: dict):
     """The frozen parameter tree for `seed` (:func:`weights_fn`)."""
-    return weights_fn(shapes, a_scale)(*seed_words(seed))
-
-
-def reference_weights(params: dict, conf: dict) -> dict:
-    """The arrays of the frozen tree `params` under the plain reference's
-    own names (the same device arrays, no copy)."""
-    blk = params["stack"]["blocks"]
-    lin = {}
-    for grp, names in (("attn", ("q", "k", "v", "o")),
-                       ("mlp", ("gate", "up", "down"))):
-        for n in names:
-            p = blk[grp][n]
-            lin[n] = {"w": p["w_q"], "w_scale": p["w_scale"],
-                      "a_scale": p["a_scale"]}
-    out = {"embed": params["embed"]["table"],
-           "final_norm": params["final_norm"]["scale"],
-           "lm_head": {"w": params["lm_head"]["w_q"],
-                       "w_scale": params["lm_head"]["w_scale"],
-                       "a_scale": params["lm_head"]["a_scale"]},
-           "layers": {"attn_norm": blk["attn_norm"]["scale"],
-                      "mlp_norm": blk["mlp_norm"]["scale"], **lin}}
-    if conf["qk_norm"]:
-        out["layers"]["q_norm"] = blk["attn"]["q_norm"]["scale"]
-        out["layers"]["k_norm"] = blk["attn"]["k_norm"]["scale"]
-    return out
+    return weights_fn(shapes, a_scale, rules)(*seed_words(seed))

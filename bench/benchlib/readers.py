@@ -18,6 +18,7 @@ from benchlib import costs, tracing
 class Context:
     window: object                  # window.Window
     conf: dict                      # the configuration file
+    adapter: object                 # its adapters/<name>.py
     settings: object                # engine.Settings
     kv_blocks: int
     peaks: dict
@@ -72,8 +73,8 @@ def mfu(ctx: Context):
     toks, keys, prompts, p_keys = _token_positions(w)
     if toks == 0 or not ctx.peaks:
         return None
-    ops = costs.model_ops(ctx.conf, toks + sum(prompts),
-                          toks + len(prompts), keys + p_keys)
+    ops = ctx.adapter.model_ops(ctx.conf, toks + sum(prompts),
+                                toks + len(prompts), keys + p_keys)
     return 100.0 * ops / (w.seconds * ctx.peaks["int8_ops_per_s"])
 
 

@@ -5,14 +5,26 @@ per-layer metric sits in a file of its own under the benchmark's
 directory, and is found here by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json``   the model configuration as it is run;
+  its ``adapter`` and ``reference`` keys name the two files below;
+* ``adapters/<name>.py``      what the harness knows of an architecture:
+  ``program_config(conf)`` (the program's ``ModelConfig``),
+  ``WEIGHT_RULES`` (leaf name -> rule, beyond ``model.COMMON_RULES``),
+  ``reference_weights(params, conf)`` (the frozen tree under the
+  reference's names), ``model_ops(conf, tokens, heads_out, ctx_sum)``,
+  ``kv_layout(cfg)`` (KV heads, head width, query heads per KV head) and
+  ``stand_in_config(cfg)`` (every width 1, for the host stand-in);
+* ``references/<name>.py``    the plain reference: ``reference_logits(
+  weights, model, tokens, read_at, *, weight_bits)`` and ``jitted(model,
+  weight_bits)``, its jitted forward;
 * ``traffic/<mix>.json``      a generator ``kind`` plus its parameters,
   read by ``traffic/<kind>.py``;
 * ``metrics/<metric>.py``     one reader per per-layer metric;
-* ``references/<name>.py``    the plain reference a configuration names;
+* ``limits/<cell>.json``      the limit of each number the check compares;
 * ``peaks.json``              device peaks keyed by ``device_kind``.
 
-A later change adds a cell by adding files and entries; nothing here
-needs an edit for it.
+A later change adds a cell, of an architecture the harness has not seen
+too, by adding files and entries; nothing here or elsewhere in
+``benchlib/`` or ``run.py`` needs an edit for it.
 """
 from __future__ import annotations
 
@@ -53,6 +65,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: dict                 # configs/<config>.json
+    adapter: object              # adapters/<name>.py the config names
     traffic: dict                # traffic/<mix>.json
     end_to_end: list[dict]       # the end-to-end metrics this cell reports
     per_layer: list[dict]        # the per-layer metrics this cell reports
@@ -73,6 +86,21 @@ class Cell:
         return load_module(self.bench_dir / "metrics" / f"{name}.py").read
 
 
+def load_adapter(config: dict, config_path: pathlib.Path,
+                 bench_dir: pathlib.Path):
+    """The adapter module the configuration file names.  A file that
+    names none, or one that does not exist, is an error naming the file."""
+    name = config.get("adapter")
+    if name is None:
+        raise ValueError(f"{config_path} names no adapter: give it "
+                       f"\"adapter\": \"<name>\" of adapters/<name>.py")
+    path = bench_dir / "adapters" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{config_path} names adapter {name!r}, "
+                                f"but {path} does not exist")
+    return load_module(path)
+
+
 def _reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -88,11 +116,13 @@ def load_cell(name: str, bench_json: pathlib.Path | None = None,
     if name not in cells:
         raise KeyError(f"no workload {name!r}; have {sorted(cells)}")
     w = cells[name]
-    config = read_json(bench_dir / "configs" / f"{w['config']}.json")
+    config_path = bench_dir / "configs" / f"{w['config']}.json"
+    config = read_json(config_path)
     traffic = read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        traffic_name=w["traffic"], config=config, traffic=traffic,
+        traffic_name=w["traffic"], config=config,
+        adapter=load_adapter(config, config_path, bench_dir), traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
         bench_dir=bench_dir)

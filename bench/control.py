@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     st = run.set_up(cell, seeds[0])
     shapes = jax.eval_shape(lambda: st.params)
     a_scale = float(cell.config["engine"]["a_scale"])
-    make = bm.weights_fn(shapes, a_scale)
+    make = bm.weights_fn(shapes, a_scale, cell.adapter.WEIGHT_RULES)
     for i, seed in enumerate(seeds):
         if i:
             plain, reqs, records = run.requests_of(cell, seed)

@@ -26,12 +26,12 @@ applied exactly, in float32 around it:
 requantized to int4 per output channel (codes in [-7, 7]), the step below
 int8 that would halve the bytes a decode step streams.
 
-Weights are a plain dict (see ``bench/benchlib/model.reference_weights``):
-``embed`` [V_pad, d], ``final_norm`` [d], ``lm_head`` {w, w_scale,
-a_scale}, and ``layers`` with a leading layer axis on every leaf:
-``attn_norm``, ``mlp_norm``, ``q_norm``/``k_norm`` (Qwen3), and the
-linears ``q k v o gate up down`` as {w [L, K, N] int8, w_scale [L, N],
-a_scale [L]}.
+Weights are a plain dict, named from the served tree by
+``bench/adapters/dense_decoder.reference_weights``: ``embed`` [V_pad,
+d], ``final_norm`` [d], ``lm_head`` {w, w_scale, a_scale}, and
+``layers`` with a leading layer axis on every leaf: ``attn_norm``,
+``mlp_norm``, ``q_norm``/``k_norm`` (Qwen3), and the linears ``q k v o
+gate up down`` as {w [L, K, N] int8, w_scale [L, N], a_scale [L]}.
 """
 from __future__ import annotations
 
@@ -139,6 +139,11 @@ def logits_at(weights: dict, model: dict, tokens, read_at, *,
     return logits[:, :int(model["vocab_size"])]
 
 
+# the configuration keys the forward reads (its jit cache keys on them)
+MODEL_KEYS = ("num_attention_heads", "num_key_value_heads", "head_dim",
+              "rms_norm_eps", "rope_theta", "qk_norm", "vocab_size")
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted(model_items: tuple, weight_bits: int):
     model = dict(model_items)
@@ -150,10 +155,13 @@ def _jitted(model_items: tuple, weight_bits: int):
     return jax.jit(fn)
 
 
+def jitted(model: dict, weight_bits: int = 8):
+    """The jitted forward ``fn(weights, tokens, read_at) -> logits`` of
+    :func:`logits_at` for the configuration `model`, one per precision."""
+    return _jitted(tuple((k, model[k]) for k in MODEL_KEYS), int(weight_bits))
+
+
 def reference_logits(weights: dict, model: dict, tokens, read_at, *,
                      weight_bits: int = 8):
     """Jitted :func:`logits_at` (one program per shape and precision)."""
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "rms_norm_eps", "rope_theta", "qk_norm", "vocab_size")
-    items = tuple((k, model[k]) for k in keys)
-    return _jitted(items, int(weight_bits))(weights, tokens, read_at)
+    return jitted(model, weight_bits)(weights, tokens, read_at)

@@ -41,14 +41,16 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
 
     cell = spec.load_cell(args.workload)
-    conf = cell.config
+    conf, adapter = cell.config, cell.adapter
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    cfg, plan = bm.program_config(conf), bm.deployment_plan(conf)
+    cfg, plan = adapter.program_config(conf), bm.deployment_plan(conf)
     a_scale = float(conf["engine"]["a_scale"])
-    plain = cell.generator().generate(cell.traffic, 0, cfg.vocab)
-    s = eng.settings(cfg, conf, cell.generator().max_tokens(cell.traffic))
+    plain = cell.generator().generate(cell.traffic, 0,
+                                      int(conf["vocab_size"]))
+    s = eng.settings(conf, cell.generator().max_tokens(cell.traffic),
+                     adapter.kv_layout(cfg))
     shapes = bm.frozen_shapes(cfg, plan, a_scale)
     placed = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=one), shapes)
@@ -64,8 +66,8 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     lo, hi = bm.seed_words(0)
     words = [jax.ShapeDtypeStruct((), np.uint32, sharding=one)] * 2
-    report("weights", bm.weights_fn(shapes, a_scale).lower(*words)
-           .compile(), t0)
+    report("weights", bm.weights_fn(shapes, a_scale, adapter.WEIGHT_RULES)
+           .lower(*words).compile(), t0)
 
     probe = eng.build(placed, cfg, plan, s, 2)
     nbr = s.max_blocks_per_req
@@ -79,13 +81,11 @@ def main(argv=None) -> int:
           f"{time.perf_counter() - t0:.1f}s", flush=True)
 
     ref = cell.reference()
-    rw = bm.reference_weights(placed, conf)
+    rw = adapter.reference_weights(placed, conf)
     seq_len = -(-s.max_blocks_per_req * s.block_size // 256) * 256
     n_read = max(r["max_new"] for r in plain)
     t0 = time.perf_counter()
-    fn = ref._jitted(tuple((k, conf[k]) for k in (
-        "num_attention_heads", "num_key_value_heads", "head_dim",
-        "rms_norm_eps", "rope_theta", "qk_norm", "vocab_size")), 8)
+    fn = ref.jitted(conf, 8)
     report(f"reference forward ({seq_len} tokens)", fn.lower(
         rw, jax.ShapeDtypeStruct((seq_len,), np.int32, sharding=one),
         jax.ShapeDtypeStruct((n_read,), np.int32, sharding=one)).compile(),

@@ -106,19 +106,21 @@ def set_up(cell, seed: int, *, kv_blocks: int | None = None,
     from benchlib import engine as eng, model as bm, spec
     from repro.launch import runtime
 
-    conf = cell.config
+    conf, adapter = cell.config, cell.adapter
     dev = jax.devices()[0]
     peaks = spec.peaks(dev.device_kind, cell.bench_dir) \
         if dev.platform == "tpu" else {}
     meter = runtime.CompileMeter()
-    cfg = bm.program_config(conf)
+    cfg = adapter.program_config(conf)
     plan = bm.deployment_plan(conf)
     a_scale = float(conf["engine"]["a_scale"])
-    s = eng.settings(cfg, conf, cell.generator().max_tokens(cell.traffic))
+    s = eng.settings(conf, cell.generator().max_tokens(cell.traffic),
+                     adapter.kv_layout(cfg))
 
     t0 = time.perf_counter()
     shapes = bm.frozen_shapes(cfg, plan, a_scale)
-    params = jax.block_until_ready(bm.make_weights(shapes, seed, a_scale))
+    params = jax.block_until_ready(
+        bm.make_weights(shapes, seed, a_scale, adapter.WEIGHT_RULES))
     n_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     log(f"weights: {n_bytes / 2**30:.3f} GiB in "
         f"{time.perf_counter() - t0:.1f}s")
@@ -175,7 +177,8 @@ def prepare(st: Setup, reqs, seconds: float, *,
         steps_per_s = st.peaks["hbm_bytes_per_s"] / w_bytes
     t0 = time.perf_counter()
     progs, shadow = eng.shadow_programs(
-        st.cfg, st.engine.plan, st.settings, st.kv_blocks, reqs,
+        st.cell.adapter.stand_in_config(st.cfg), st.engine.plan,
+        st.settings, st.kv_blocks, reqs,
         open_after_steps=mean_lifetime(st.settings, reqs),
         window_steps=math.ceil(seconds * steps_per_s))
     t1 = time.perf_counter()
@@ -240,7 +243,7 @@ def check_window(st: Setup, w, prompts: dict, control: bool = False
     readings on the same sequences, under ``"control"``."""
     import numpy as np
 
-    from benchlib import check, model as bm
+    from benchlib import check
     t0 = time.perf_counter()
     sample = check.pick(w, st.seed, CHECK_TOKENS, CHECK_REQUESTS)
     s = st.settings
@@ -249,7 +252,8 @@ def check_window(st: Setup, w, prompts: dict, control: bool = False
     items = [(prompts[r.rid], np.asarray(r.tokens, np.int32))
              for r in sample]
     got = check.readings(st.cell.reference().reference_logits,
-                         bm.reference_weights(st.params, st.cell.config),
+                         st.cell.adapter.reference_weights(
+                             st.params, st.cell.config),
                          st.cell.config, items, seq_len, n_read,
                          control=control) if items else {}
     got["requests"] = len(sample)
@@ -323,8 +327,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         tr = tracing.extract(str(trace_dir))
         span = tracing.window(tr)
         ctx = readers.Context(
-            window=w, conf=cell.config, settings=st.settings,
-            kv_blocks=st.kv_blocks, peaks=st.peaks,
+            window=w, conf=cell.config, adapter=cell.adapter,
+            settings=st.settings, kv_blocks=st.kv_blocks, peaks=st.peaks,
             compiles_in_window=marks["compiles"], trace=tr, span=span)
         metrics = {}
         for m in cell.per_layer:

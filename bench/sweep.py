@@ -57,17 +57,18 @@ def queue(records: dict, step_open: int, step_close: int) -> dict:
 
 def host_sweep(cell, rates, seeds, kv_blocks: int, steps: int) -> None:
     from benchlib import engine as eng, model as bm
-    cfg, plan = bm.program_config(cell.config), bm.deployment_plan(
-        cell.config)
-    s = eng.settings(cfg, cell.config,
-                     cell.generator().max_tokens(cell.traffic))
+    cfg = cell.adapter.program_config(cell.config)
+    plan = bm.deployment_plan(cell.config)
+    s = eng.settings(cell.config, cell.generator().max_tokens(cell.traffic),
+                     cell.adapter.kv_layout(cfg))
+    stand_in = cell.adapter.stand_in_config(cfg)
     for rate in rates:
         mix = copy.deepcopy(cell.traffic)
         mix["arrivals"]["rate_per_step"] = rate
         for seed in seeds:
             _, reqs, _ = run.requests_of(cell, seed, mix)
             life = run.mean_lifetime(s, reqs)
-            _, w = eng.shadow_programs(cfg, plan, s, kv_blocks, reqs,
+            _, w = eng.shadow_programs(stand_in, plan, s, kv_blocks, reqs,
                                        open_after_steps=life,
                                        window_steps=steps)
             print(json.dumps({"rate_per_step": rate, "seed": seed,

@@ -30,8 +30,9 @@ def served(tmp_path_factory):
     w, marks = run.serve(st, reqs, records, 2.0, trace=True,
                          trace_dir=tmp / "trace")
     tr = tracing.extract(str(tmp / "trace"))
-    ctx = readers.Context(window=w, conf=cell.config, settings=st.settings,
-                          kv_blocks=st.kv_blocks, peaks={},
+    ctx = readers.Context(window=w, conf=cell.config, adapter=cell.adapter,
+                          settings=st.settings, kv_blocks=st.kv_blocks,
+                          peaks={},
                           compiles_in_window=marks["compiles"], trace=tr,
                           span=tracing.window(tr))
     return st, tr, ctx
@@ -134,7 +135,8 @@ def made():
               op("jit_serve_decode_segment", 1205, 195,
                  tracing.MODULES_LINE)]
     tr = {"device": device, "host": host}
-    return readers.Context(window=None, conf={}, settings=None, kv_blocks=0,
+    return readers.Context(window=None, conf={}, adapter=None,
+                           settings=None, kv_blocks=0,
                            peaks={}, compiles_in_window=0, trace=tr,
                            span=tracing.window(tr))
 

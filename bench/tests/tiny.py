@@ -1,5 +1,6 @@
 """A toy benchmark directory for the tests: the real traffic generator,
-reference and metric readers, with a two-layer configuration."""
+adapters, references and metric readers, with a four-layer
+configuration."""
 from __future__ import annotations
 
 import json
@@ -26,7 +27,7 @@ LIMITS = {"widest_gap": 1.0, "mean_gap": 0.06}
 def make(tmp: pathlib.Path, plan=None) -> pathlib.Path:
     """A benchmark directory under `tmp` holding cell ``tiny.chat``."""
     d = tmp / "bench"
-    for sub in ("traffic", "references", "metrics"):
+    for sub in ("adapters", "traffic", "references", "metrics"):
         shutil.copytree(BENCH / sub, d / sub)
     shutil.copy(BENCH / "peaks.json", d / "peaks.json")
     (d / "configs").mkdir()
